@@ -1,6 +1,7 @@
 """Shared benchmark helpers: datasets, ground truth, metrics, timing."""
 from __future__ import annotations
 
+import os
 import sys
 import time
 from pathlib import Path
@@ -71,6 +72,34 @@ def timed(fn, *args, repeats: int = 3, **kw):
         out = jax.block_until_ready(fn(*args, **kw))
         ts.append(time.perf_counter() - t0)
     return out, float(np.median(ts))
+
+
+def cpu_worker_env(device_count: int | None = None) -> dict:
+    """Environment for a benchmark's CPU measurement subprocess (fake host
+    devices via XLA_FLAGS, `repro` and `benchmarks` importable).  Refuses on
+    an accelerator host: the worker would time the CPU in the chip's place,
+    or contend with this process for the chip."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"this benchmark spawns CPU-only workers and would not measure "
+            f"the {backend!r} device this process sees; run chip_smoke.py "
+            f"on an accelerator host instead"
+        )
+    env = dict(os.environ)
+    if device_count is not None:
+        env["XLA_FLAGS"] = (
+            env.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={device_count}"
+        ).strip()
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    root = Path(__file__).resolve().parent.parent
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(root / "src"), str(root), env.get("PYTHONPATH", "")]
+    ).rstrip(os.pathsep)
+    return env
 
 
 class CsvRows:

@@ -32,12 +32,11 @@ the records land in BENCH_search.json under "sharded" (see run.py).
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path
 
-from .common import CsvRows
+from .common import CsvRows, cpu_worker_env
 
 _MARK = "FIG13-JSON:"
 
@@ -46,16 +45,8 @@ def run(csv: CsvRows, n: int = 4000, shard_counts=(1, 2, 4, 8),
         queries: int = 32):
     """Spawn the measurement subprocess (max(shard_counts) fake devices) and
     fold its records into csv + the returned BENCH payload."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={max(shard_counts)}"
-    ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = cpu_worker_env(max(shard_counts))
     root = Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root), env.get("PYTHONPATH", "")]
-    ).rstrip(os.pathsep)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.fig13_sharded", "--worker",
          "--n", str(n), "--queries", str(queries),

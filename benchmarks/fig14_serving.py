@@ -61,7 +61,6 @@ attribution (the no-silent-retrace check needs per-engine deltas).
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import time
@@ -69,7 +68,7 @@ from pathlib import Path
 
 import numpy as np
 
-from .common import CsvRows
+from .common import CsvRows, cpu_worker_env
 
 _MARK = "FIG14-JSON:"
 
@@ -173,12 +172,8 @@ def run(csv: CsvRows, *, corpus_docs: int = 160, max_batch: int = 8,
         levels=(0.7, 0.85, 0.95, 1.05, 1.15), sweep_cap: int = 960) -> dict:
     """Spawn the measurement subprocess (fresh jax runtime, quiet heap) and
     fold its payload into csv + the returned BENCH block."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = cpu_worker_env()
     root = Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root), env.get("PYTHONPATH", "")]
-    ).rstrip(os.pathsep)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.fig14_serving", "--worker",
          "--corpus-docs", str(corpus_docs), "--max-batch", str(max_batch),

@@ -22,12 +22,11 @@ JSON line back; run.py folds the payload into BENCH_search.json under
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path
 
-from .common import CsvRows
+from .common import CsvRows, cpu_worker_env
 
 _MARK = "TRACE-JSON:"
 
@@ -36,16 +35,8 @@ def run(csv: CsvRows, n: int = 1500, queries: int = 32, repeats: int = 5,
         trace_path: str = "BENCH_trace.json") -> dict:
     """Spawn the measurement subprocess (2 fake devices for the sharded
     topology) and fold per-stage means into csv + the returned payload."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=2"
-    ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = cpu_worker_env(2)
     root = Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root), env.get("PYTHONPATH", "")]
-    ).rstrip(os.pathsep)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.stage_breakdown", "--worker",
          "--n", str(n), "--queries", str(queries),

@@ -54,7 +54,7 @@ NUMPY_CONCRETIZERS = {"numpy.asarray", "numpy.array", "numpy.float32",
                       "numpy.float64", "numpy.int32", "numpy.int64"}
 JIT_NAMES = {"jax.jit", "jax.pmap"}
 TRACE_WRAPPERS = {"jax.jit", "jax.pmap", "jax.experimental.pallas.pallas_call",
-                  "jax.experimental.shard_map.shard_map"}
+                  "jax.shard_map"}
 # kwargs of the trace wrappers that key a trace cache (or pin kernel
 # structure) and therefore must not alias mutable state
 TRACE_CONFIG_KWARGS = {"static_argnums", "static_argnames", "donate_argnums",

@@ -200,7 +200,7 @@ class LCCSIndex:
         data = jnp.asarray(data, dtype=jnp.float32)
         n, d = data.shape
         fam = lsh_mod.make_family(family, jax.random.key(seed), d, m, **family_kw)
-        h = fam.hash(data)
+        h = lsh_mod.hash_rows(fam, data)
         csa = build_csa(h) if build_csa_structure else None
         vstore = make_store(store, data)
         tail = None
@@ -261,7 +261,7 @@ class LCCSIndex:
                 fam = lsh_mod.make_family(
                     family, jax.random.key(seed), rows.shape[1], m, **family_kw
                 )
-            hc = fam.hash(rows)
+            hc = lsh_mod.hash_rows(fam, rows)
             h_parts.append(np.asarray(hc, np.int32))
             sizes.append(rows.shape[0])
             if build_csa_structure:

@@ -71,7 +71,11 @@ class RandomProjectionLSH:
         return self.a.shape[0]
 
     def projections(self, x: jax.Array) -> jax.Array:
-        return x.astype(jnp.float32) @ self.a + self.b
+        # HIGHEST: the TPU's default matmul precision rounds the operands to
+        # bf16, which moves bucket-edge hashes away from an fp32 (CPU) build
+        # of the same rows
+        return jnp.matmul(x.astype(jnp.float32), self.a,
+                          precision=jax.lax.Precision.HIGHEST) + self.b
 
     def hash(self, x: jax.Array) -> jax.Array:
         proj = self.projections(x)
@@ -158,7 +162,8 @@ class CrossPolytopeLSH:
     def _rotate(self, x: jax.Array) -> jax.Array:
         """(n, d) -> (n, m, dr) rotated copies."""
         if self.rotation == "gaussian":
-            return jnp.einsum("nd,mde->nme", x, self.rot)
+            return jnp.einsum("nd,mde->nme", x, self.rot,
+                              precision=jax.lax.Precision.HIGHEST)
         n = x.shape[0]
         xp = jnp.pad(x, ((0, 0), (0, self.dr - self.d)))
         y = xp[:, None, :] * self.signs[None, :, 0, :]  # (n, m, dr)
@@ -249,6 +254,34 @@ def make_family(kind: str, key: jax.Array, d: int, m: int, **kw):
     if kind in ("bits", "hamming", "bit_sampling"):
         return BitSamplingLSH.create(key, d, m)
     raise ValueError(f"unknown LSH family {kind!r}")
+
+
+# data rows are hashed in blocks of this many rows, all by the one program
+# compiled for that block shape
+HASH_BLOCK_ROWS = 4096
+
+_hash_block = jax.jit(lambda family, x: family.hash(x))
+
+
+def hash_rows(family, x) -> jax.Array:
+    """(n, d) data rows -> (n, m) int32 hash strings, computed one
+    fixed-shape block at a time.  Every path that hashes corpus rows (the
+    monolithic and streaming builds, inserts, chunked ingest) goes through
+    here, so a row's hash string never depends on the rows hashed with it:
+    on a TPU the fp32 projection can round differently for a different
+    batch shape, and `floor` turns one ulp at a bucket edge into another
+    hash value -- enough for a segmented index to answer differently from a
+    rebuild over the same rows."""
+    n = x.shape[0]
+    if n == 0:
+        return family.hash(jnp.asarray(x, jnp.float32))
+    blocks = []
+    for lo in range(0, n, HASH_BLOCK_ROWS):
+        xb = jnp.asarray(x[lo:lo + HASH_BLOCK_ROWS], jnp.float32)
+        if xb.shape[0] < HASH_BLOCK_ROWS:
+            xb = jnp.pad(xb, ((0, HASH_BLOCK_ROWS - xb.shape[0]), (0, 0)))
+        blocks.append(_hash_block(family, xb))
+    return jnp.concatenate(blocks)[:n]
 
 
 def distance(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:

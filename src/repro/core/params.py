@@ -60,13 +60,13 @@ use_gather_kernel
              (`kernels.gather_l2`) and int8 (`kernels.gather_q`):
              True = the scalar-prefetch Pallas gather kernels, False = the
              dense jnp gather, None = the REPRO_GATHER_KERNEL env var when
-             set, else on for TPU backends only (interpret-mode Pallas on CPU
-             is correct but slow).
+             set, else off on every backend (the kernels' (1, d) row blocks
+             do not lower for TPU; off-TPU they run in interpret mode).
 use_probe_kernel
              probe-stage kernel toggle (`kernels.csa_probe`): True = the
              fused CSA probe (binary search + adjacent-LCP window walk +
-             scatter-max dedupe in one pass -- Pallas on TPU, the fused jnp
-             reference elsewhere), False = the legacy
+             scatter-max dedupe in one pass, run as its fused jnp form on
+             every backend), False = the legacy
              `core.search.klccs_search*` window path, None = the
              REPRO_PROBE_KERNEL env var when set, else on for TPU backends
              only.  Outputs are bit-identical either way; the "lccs" and

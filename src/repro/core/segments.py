@@ -56,7 +56,7 @@ from repro.exec import execute as _execute, stages as exec_stages
 from repro.store import make_store
 
 from . import lsh as lsh_mod
-from .bruteforce import circ_run_lengths
+from .bruteforce import circ_run_lengths, top_lengths
 from .csa import CSA, build_csa, build_csa_chunked
 from .index import LCCSIndex, _reblock
 from .params import SearchParams
@@ -270,7 +270,7 @@ class SegmentedLCCSIndex:
         b = X.shape[0]
         if b == 0:
             return np.zeros((0,), np.int32)
-        h = self.family.hash(X)
+        h = lsh_mod.hash_rows(self.family, X)
         n_ids, fill = self.n_ids, self.buffer_count
         gids = np.arange(n_ids, n_ids + b, dtype=np.int32)
         self._grow_store(n_ids + b)
@@ -321,7 +321,7 @@ class SegmentedLCCSIndex:
             b = X.shape[0]
             if b == 0:
                 continue
-            h = self.family.hash(X)
+            h = lsh_mod.hash_rows(self.family, X)
             n_ids = self.n_ids
             gids = np.arange(n_ids, n_ids + b, dtype=np.int32)
             self._grow_store(n_ids + b)
@@ -500,7 +500,7 @@ def _buffer_topk(index: SegmentedLCCSIndex, qh: jax.Array, lam: int):
     def one(q):
         lens = jnp.where(ok, circ_run_lengths(index.buf_h, q), -1)
         kk = min(lam, lens.shape[0])
-        vals, slot = jax.lax.top_k(lens, kk)
+        vals, slot = top_lengths(lens, kk, index.m)
         ids = jnp.where(vals >= 0, index.buf_gid[slot], -1)
         return ids, jnp.where(vals >= 0, vals, -1)
 

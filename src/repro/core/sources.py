@@ -96,7 +96,7 @@ def _require_csa(index, name):
 
 
 def _fused_probe(index, params) -> bool:
-    """True when this probe runs the fused CSA kernel (`kernels.csa_probe`):
+    """True when this probe runs the fused CSA probe (`kernels.csa_probe`):
     the resolved `use_probe_kernel` toggle is on AND the CSA carries the
     adjacent-LCP table the fused window walk needs.  Bit-identical outputs
     either way -- the toggle is purely a performance dispatch."""
@@ -121,12 +121,9 @@ def lccs_source(index, queries, qh, params):
     _require_csa(index, "lccs")
     width = params.resolved_width()
     if params.mode == "parallel" and _fused_probe(index, params):
-        from repro.kernels.csa_probe import csa_probe_search, default_use_pallas
+        from repro.kernels.csa_probe import csa_probe_search
 
-        return csa_probe_search(
-            index.csa, qh, params.lam, width=width,
-            use_pallas=default_use_pallas(),
-        )
+        return csa_probe_search(index.csa, qh, params.lam, width=width)
     return klccs_search(
         index.csa, qh, params.lam, width=width, mode=params.mode
     )
@@ -164,12 +161,11 @@ def multiprobe_full_source(index, queries, qh, params):
         # by its best probe's inner top-lam is outranked by >= lam ids whose
         # merged values only grow, so it cannot enter the global top-lam.
         from repro.kernels.csa_probe import (
-            csa_probe_windows, dedupe_topk_scatter, default_use_pallas,
+            csa_probe_windows, dedupe_topk_scatter,
         )
 
         w_ids, w_lcps = csa_probe_windows(
             index.csa, strings.reshape(B * P, m), width=width,
-            use_pallas=default_use_pallas(),
         )
         return dedupe_topk_scatter(
             w_ids.reshape(B, -1), w_lcps.reshape(B, -1), index.csa.n,
@@ -203,16 +199,12 @@ def multiprobe_skip_source(index, queries, qh, params):
     if fused:
         from repro.kernels.csa_probe import (
             csa_probe_pairs, csa_probe_windows, dedupe_topk_scatter,
-            default_use_pallas,
         )
 
-        use_pallas = default_use_pallas()
         # raw base windows: the scatter-max merge below dedupes the whole
         # pool at once, so no intermediate top-lam cut is needed (and the
         # per-shift max of the window LCPs IS the §4.2 len bound)
-        w_ids, w_lcps = csa_probe_windows(
-            index.csa, qh, width=width, use_pallas=use_pallas
-        )
+        w_ids, w_lcps = csa_probe_windows(index.csa, qh, width=width)
         B0 = qh.shape[0]
         base_ids = w_ids.reshape(B0, -1)
         base_lcps = w_lcps.reshape(B0, -1)
@@ -259,7 +251,7 @@ def multiprobe_skip_source(index, queries, qh, params):
     if fused:
         p_ids, p_lcps = csa_probe_pairs(
             index.csa, rows, shifts.reshape(-1), valid.reshape(-1),
-            width=width, use_pallas=use_pallas,
+            width=width,
         )
     else:
         p_ids, p_lcps = klccs_search_pairs(

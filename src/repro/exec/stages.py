@@ -37,7 +37,8 @@ collapse verification to `exact_topk` -- bit-identical to the seed
 `verify_candidates` on the reference route; quantized stores run
 `survivors -> gather_fp32 -> rerank_rows` with one kernel dispatch point
 (`resolve_use_kernel`) shared by the fp32 (`kernels.gather_l2`) and int8
-(`kernels.gather_q`) Pallas kernels.
+(`kernels.gather_q`) Pallas kernels, which are opt-in: the default on every
+backend, TPU included, is the XLA gather.
 """
 from __future__ import annotations
 
@@ -100,6 +101,12 @@ def probe(index, queries: jax.Array, qh: jax.Array, params):
 def resolve_use_kernel(flag: bool | None) -> bool:
     """Tri-state resolution of `SearchParams.use_gather_kernel`.
 
+    The default is the XLA gather on every backend.  The Pallas gather
+    kernels (`kernels.gather_l2`, `kernels.gather_q`) DMA one (1, d) row
+    block per candidate, which the TPU lowering refuses (the last two block
+    dims must divide by 8 and 128), so they run only when asked for, and
+    only in interpret mode off-TPU.
+
     Plan building (`repro.exec.plan`) resolves None to a concrete bool
     *before* jitting, so the choice is part of the plan key.  Direct callers
     of the pure pipeline functions passing None get trace-time resolution
@@ -111,13 +118,16 @@ def resolve_use_kernel(flag: bool | None) -> bool:
     env = os.environ.get(ENV_GATHER_KERNEL)
     if env is not None:
         return env.strip().lower() not in ("", "0", "false", "off")
-    return jax.default_backend() == "tpu"
+    return False
 
 
 def resolve_use_probe_kernel(flag: bool | None) -> bool:
     """Tri-state resolution of `SearchParams.use_probe_kernel` -- the probe
     stage's dispatch between the fused CSA probe (`kernels.csa_probe`) and
-    the legacy `core.search` window path.  Same contract as
+    the legacy `core.search` window path.  On by default on TPU backends,
+    where the fused probe runs as XLA (its jnp form, `csa_probe/ref.py`):
+    the Pallas `csa_probe` kernel is refused by the TPU lowering and is
+    VMEM-bounded to n <= ~32k at m=64 besides.  Same contract as
     `resolve_use_kernel`: plan building pins None to a concrete bool before
     jitting so the choice keys the plan; direct callers passing None get
     trace-time resolution (a later env flip cannot invalidate a cached

@@ -3,7 +3,6 @@ from .ops import (
     csa_probe_search,
     csa_probe_search_with_lens,
     csa_probe_windows,
-    default_use_pallas,
     supports,
 )
 from .ref import dedupe_topk_scatter
@@ -14,6 +13,5 @@ __all__ = [
     "csa_probe_search_with_lens",
     "csa_probe_windows",
     "dedupe_topk_scatter",
-    "default_use_pallas",
     "supports",
 ]

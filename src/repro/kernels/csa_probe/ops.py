@@ -8,11 +8,14 @@ points, selected by `SearchParams.use_probe_kernel` / REPRO_PROBE_KERNEL
   csa_probe_search_with_lens  == klccs_search_with_lens
   csa_probe_pairs             == klccs_search_pairs
 
-`use_pallas` picks the Pallas kernel (interpret-mode off-TPU) vs the fused
-pure-jnp reference -- both bit-identical to the legacy path; the reference
-form is also the fast CPU route (the legacy window gathers ~W x more HBM
-words and dedupes with two stable argsorts, see ref.py).  Requires a CSA
-built with the adjacent-LCP table (`csa.L`); `supports(csa)` gates that.
+`use_pallas` picks the Pallas kernel (interpret mode, tests only) vs the
+fused pure-jnp reference -- both bit-identical to the legacy path.  The
+reference form is the route every platform runs (the legacy window gathers
+~W x more HBM words and dedupes with two stable argsorts, see ref.py): the
+Pallas kernel's (1, n) / (n, 2m) blocks are refused by the TPU lowering,
+and its VMEM-resident Hd bounds it at n <= ~32k for m=64 besides, so no
+size selects it.  Requires a CSA built with the adjacent-LCP table
+(`csa.L`); `supports(csa)` gates that.
 """
 from __future__ import annotations
 
@@ -30,12 +33,6 @@ def supports(csa) -> bool:
     """True when `csa` carries the adjacent-LCP table the fused path needs
     (absent only on artifacts saved before the table existed)."""
     return csa is not None and csa.L is not None
-
-
-def default_use_pallas() -> bool:
-    """Pallas on real TPUs; the fused jnp reference elsewhere (interpret-mode
-    Pallas is exact but slow -- tests opt into it explicitly)."""
-    return not default_interpret()
 
 
 def _windows(csa, qd, shifts, qidx, width: int, use_pallas: bool):

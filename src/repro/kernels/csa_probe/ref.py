@@ -30,6 +30,20 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def _exclusive_running_min(x: jax.Array, fill: int) -> jax.Array:
+    """out[j] = min(fill, x[0], .., x[j-1]) by log2(len) shift-and-min steps.
+    Not `lax.associative_scan` / `cummin`: vmapped over a multiprobe
+    worklist (~10^4 rows) those take the TPU compiler minutes."""
+    x = jnp.concatenate([jnp.full((1,), fill, x.dtype), x[:-1]])
+    s = 1
+    while s < x.shape[0]:
+        x = jnp.minimum(
+            x, jnp.concatenate([jnp.full((s,), fill, x.dtype), x[:-s]])
+        )
+        s *= 2
+    return x
+
+
 def window_from_adjacent(csa, qd_r: jax.Array, i: jax.Array, pos: jax.Array,
                          width: int):
     """LCPs of the 2W-slot window around insertion position `pos` in I[i],
@@ -58,23 +72,18 @@ def window_from_adjacent(csa, qd_r: jax.Array, i: jax.Array, pos: jax.Array,
     adj_down = jnp.where(
         pos - 2 - jj >= 0, csa.L[i, jnp.clip(pos - 2 - jj, 0, n - 1)], m
     )
-    run_down = lax.associative_scan(jnp.minimum, adj_down)
-    down = jnp.minimum(
-        lcp_l, jnp.concatenate([jnp.array([m], jnp.int32), run_down[:-1]])
-    )
+    down = jnp.minimum(lcp_l, _exclusive_running_min(adj_down, m))
     # up chain: lcp(q, sorted[pos+j]) = min(lcp_u, L[pos], .., L[pos+j-1])
     adj_up = jnp.where(
         pos + jj <= n - 2, csa.L[i, jnp.clip(pos + jj, 0, n - 1)], m
     )
-    run_up = lax.associative_scan(jnp.minimum, adj_up)
-    up = jnp.minimum(
-        lcp_u, jnp.concatenate([jnp.array([m], jnp.int32), run_up[:-1]])
-    )
-    lcps = jnp.where(
-        ps >= pos,
-        up[jnp.clip(ps - pos, 0, width - 1)],
-        down[jnp.clip(pos - 1 - ps, 0, width - 1)],
-    ).astype(jnp.int32)
+    up = jnp.minimum(lcp_u, _exclusive_running_min(adj_up, m))
+    # slot pos+o reads up[o] (o >= 0) or down[-o-1] (o < 0): a fixed layout.
+    # Slots clipped at either end of the sorted order agree with the clipped
+    # position's own slot, because both chains stay constant past the ends
+    # (out-of-range L reads m, and at pos == 0 / pos == n the two boundary
+    # rows coincide), so no per-row gather is needed.
+    lcps = jnp.concatenate([down[::-1], up]).astype(jnp.int32)
     return ids, lcps
 
 

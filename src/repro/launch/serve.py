@@ -41,6 +41,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS
 from repro.core import SearchParams, available_sources, available_stores
 from repro.data.synthetic import lm_token_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import RetrievalEngine
 from repro.train.step import init_train_state
@@ -84,21 +85,23 @@ def _wait_ready(router, timeout_s: float = 120.0, poll_s: float = 0.1) -> float:
     )
 
 
-def _serve_async(engine, corpus, picks, args, search_params) -> None:
+def serve_async(engine, corpus, picks, search_params, *, replicas: int,
+                slo_ms: float, queue_depth: int) -> dict:
     """The --async serving path: replicate the engine, warm + probe
     readiness, push the request stream through the deadline-aware front,
-    and report the SLO window + per-replica retrace audit."""
+    and report the SLO window + per-replica retrace audit.  Returns the
+    window's summary: requests, self-retrieval hits, rejections, SLO misses
+    and the plan compiles/evictions counted after warm()."""
     from repro.router import QueueFull, Router
 
-    router = Router.replicate(engine, args.replicas, params=search_params,
-                              default_slo_ms=args.slo_ms,
-                              max_depth=args.queue_depth)
+    router = Router.replicate(engine, replicas, params=search_params,
+                              default_slo_ms=slo_ms, max_depth=queue_depth)
     try:
         router.warm(corpus[: engine.max_batch])
         ready_s = _wait_ready(router)
         print(f"[launch.serve] router ready in {ready_s*1e3:.0f} ms "
-              f"({args.replicas} replicas, slo {args.slo_ms:.0f} ms, "
-              f"queue depth {args.queue_depth})")
+              f"({replicas} replicas, slo {slo_ms:.0f} ms, "
+              f"queue depth {queue_depth})")
         t0 = time.perf_counter()
         tickets, rejected = [], 0
         for i in picks:
@@ -130,6 +133,15 @@ def _serve_async(engine, corpus, picks, args, search_params) -> None:
                 f"{r.serve['plan_hits']} reuses / "
                 f"{r.serve['plan_evictions']} evictions"
             )
+        return {
+            "requests": len(tickets),
+            "hits": hits,
+            "rejected": st.rejected,
+            "deadline_misses": st.deadline_misses,
+            "plan_misses": sum(r.serve["plan_misses"] for r in st.replicas),
+            "plan_evictions": sum(r.serve["plan_evictions"]
+                                  for r in st.replicas),
+        }
     finally:
         router.shutdown()
 
@@ -217,6 +229,7 @@ def main():
                  "corpora ingest out of core via "
                  "SegmentedLCCSIndex.ingest_chunks")
     _ensure_devices(args.shards)
+    enable_compile_cache()
 
     # any width-vs-lam warning fires once, on the from_legacy construction;
     # the chained field replaces below derive from the same user choice
@@ -284,7 +297,9 @@ def main():
     rng = np.random.default_rng(1)
     picks = rng.integers(0, args.corpus, args.requests)
     if args.async_serve:
-        _serve_async(engine, corpus, picks, args, search_params)
+        serve_async(engine, corpus, picks, search_params,
+                    replicas=args.replicas, slo_ms=args.slo_ms,
+                    queue_depth=args.queue_depth)
         _obs_epilogue(engine, corpus, args, search_params, metrics_srv,
                       stats_log)
         return

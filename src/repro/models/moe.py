@@ -29,7 +29,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import with_logical_constraint as wlc
@@ -189,7 +188,7 @@ def _moe_sharded(p, x, cfg: MoEConfig, mesh, bf16_gather: bool = False):
         out = jnp.zeros((b_l * s_l, D), back.dtype).at[st].add(contrib)
         return out.reshape(b_l, s_l, D).astype(x_l.dtype), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(
@@ -200,7 +199,7 @@ def _moe_sharded(p, x, cfg: MoEConfig, mesh, bf16_gather: bool = False):
             P(ep, None, bd),  # e_down (E, F, D)
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, p["router"], p["e_gate"], p["e_up"], p["e_down"])
 

@@ -31,7 +31,7 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 from .registry import registry
 
@@ -174,11 +174,9 @@ def trace(path=None, *, clear: bool = True):
 def device_profile(logdir):
     """The real-accelerator hook: a context manager wrapping
     `jax.profiler.trace(logdir)` so a TPU run captures XLA device timelines
-    (TensorBoard / xprof) alongside the host-side span tree.  Falls back to
-    a no-op when the profiler is unavailable (minimal CPU builds)."""
-    try:
-        import jax
+    (TensorBoard / xprof) alongside the host-side span tree.  A profiler
+    that cannot start raises: a run that asked for a device trace never
+    silently records none."""
+    import jax
 
-        return jax.profiler.trace(str(logdir))
-    except Exception:  # pragma: no cover -- profiler not built in
-        return nullcontext()
+    return jax.profiler.trace(str(logdir))

@@ -30,12 +30,9 @@ def dequantize_int8(q: jax.Array, scale: jax.Array):
 def compress_psum_int8(grads, error_buf, axis_names: tuple[str, ...]):
     """Quantise (grad + error), psum int32 across `axis_names`, dequantise;
     returns (reduced_grads_mean, new_error_buf).  Call inside shard_map."""
-    # jax.lax.axis_size is missing on older jax; psum(1, ax) is the classic
-    # spelling and constant-folds to a concrete int inside shard_map.
-    _axis_size = getattr(jax.lax, "axis_size", lambda ax: jax.lax.psum(1, ax))
     n_dev = 1
     for ax in axis_names:
-        n_dev *= _axis_size(ax)
+        n_dev *= jax.lax.axis_size(ax)
 
     def one(g, e):
         ge = g.astype(jnp.float32) + e

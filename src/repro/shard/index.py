@@ -14,7 +14,8 @@ leaf gains a leading shard axis:
 
   h     (S, rows, m)   per-shard hash strings, sentinel-padded
   csa   CSA with leaves (S, m, rows) / (S, rows, 2m) -- one CSA per shard,
-        built per shard (vmap of `build_csa`), NOT a split of the global CSA
+        built by `build_csa` on the shard's own device (a shard_map over the
+        row-partitioned hashes), NOT a split of the global CSA
   gid   (S, rows)      global row ids, -1 on padding
   store VectorStore with leaves (S, rows, ...) -- per-shard vector slices
   tail  (S, rows, d)   per-shard fp32 rerank rows (inexact stores)
@@ -232,10 +233,11 @@ def shard_index(
     h[:n] = np.asarray(index.h)
     gid = np.full((S * rows,), -1, np.int32)
     gid[:n] = np.arange(n, dtype=np.int32)
-    hj = jnp.asarray(h.reshape(S, rows, m))
+    hj = jax.device_put(h.reshape(S, rows, m),
+                        NamedSharding(mesh, P(axis, None, None)))
     if build_csa_structure is None:
         build_csa_structure = index.csa is not None
-    csa = jax.vmap(build_csa)(hj) if build_csa_structure else None
+    csa = _build_shard_csas(hj, mesh, axis) if build_csa_structure else None
     sharded = ShardedLCCSIndex(
         family=index.family,
         store=_stack_rows(index.store, S, rows),
@@ -249,6 +251,16 @@ def shard_index(
         tail=None if index.tail is None else _stack_rows(index.tail, S, rows),
     )
     return _device_put_sharded(sharded)
+
+
+def _build_shard_csas(hj: jax.Array, mesh: Mesh, axis: str) -> CSA:
+    """One `build_csa` per shard, each on the device that holds the shard's
+    rows: a shard_map over the row-partitioned (S, rows, m) hashes, so the
+    build transients never gather on one chip."""
+    local = lambda h: jax.tree.map(lambda x: x[None], build_csa(h[0]))
+    build = jax.shard_map(local, mesh=mesh, in_specs=P(axis, None, None),
+                          out_specs=P(axis), check_vma=False)
+    return jax.jit(build)(hj)
 
 
 def _device_put_sharded(index: ShardedLCCSIndex) -> ShardedLCCSIndex:

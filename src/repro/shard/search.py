@@ -46,7 +46,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.csa import CSA
@@ -112,7 +111,7 @@ def _shard_call(index: ShardedLCCSIndex, local_fn, out_specs):
     axis = index.axis
     rep = lambda t: jax.tree.map(lambda _: P(), t)
     shd = lambda t: jax.tree.map(lambda x: _row_spec(x, axis), t)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=index.mesh,
         in_specs=(
@@ -126,7 +125,7 @@ def _shard_call(index: ShardedLCCSIndex, local_fn, out_specs):
             P(),  # query hash strings replicated
         ),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -285,7 +284,7 @@ def _shard_call_staged(index: ShardedLCCSIndex, local_fn, out_specs,
     axis = index.axis
     rep = lambda t: jax.tree.map(lambda _: P(), t)
     shd = lambda t: jax.tree.map(lambda x: _row_spec(x, axis), t)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=index.mesh,
         in_specs=(
@@ -298,7 +297,7 @@ def _shard_call_staged(index: ShardedLCCSIndex, local_fn, out_specs,
             P(),  # queries replicated
         ) + tuple(extra_in_specs),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -128,6 +128,43 @@ def test_bruteforce_topk_is_exact(h, qseed):
     np.testing.assert_array_equal(np.sort(np.asarray(vals[0]))[::-1], exact)
 
 
+@pytest.mark.parametrize("per_block", [1, 2, 3])
+def test_bruteforce_topk_blocking_changes_no_result(per_block, monkeypatch):
+    """Queries scored in blocks of `per_block` (7 queries, so the last block
+    is short) return exactly the one-block result."""
+    import jax
+
+    from repro.core import bruteforce
+
+    rng = np.random.default_rng(per_block)
+    n, m, lam = 300, 8, 16
+    h = jnp.asarray(rng.integers(0, 3, (n, m)).astype(np.int32))
+    q = jnp.asarray(rng.integers(0, 3, (7, m)).astype(np.int32))
+    want = bruteforce_topk(h, q, lam)
+    monkeypatch.setattr(bruteforce, "_SLAB_ELEMS", per_block * 2 * n * m)
+    # a fresh jit: the module's executable for these shapes is cached
+    got = jax.jit(bruteforce_topk.__wrapped__, static_argnames="lam")(
+        h, q, lam=lam)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("n,m", [(1000, 8), (4096, 64), (70_000, 2**14)])
+def test_top_lengths_ties_go_to_the_lower_id(n, m):
+    """Heavy ties (and -1 masked rows) come back in (length desc, id asc)
+    order: the packed-key path for n, m that fit an int32, and the plain
+    `lax.top_k` fallback (70000 rows x m=2^14 does not fit)."""
+    from repro.core.bruteforce import top_lengths
+
+    rng = np.random.default_rng(n)
+    lengths = rng.integers(-1, 4, n).astype(np.int32)
+    k = 300
+    vals, idx = top_lengths(jnp.asarray(lengths), k, m)
+    want = np.argsort(-lengths, kind="stable")[:k]
+    np.testing.assert_array_equal(np.asarray(idx), want)
+    np.testing.assert_array_equal(np.asarray(vals), lengths[want])
+
+
 def _assert_csa_equals_oracle(h):
     """Exact I/P equality (not just sorted-string equality): both the
     doubling-rank construction and the literal Algorithm 1 break ties by

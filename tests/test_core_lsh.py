@@ -78,6 +78,23 @@ def test_hash_values_deterministic_and_int32():
     np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
 
 
+@pytest.mark.parametrize("family", ["euclidean", "angular"])
+def test_hash_rows_blocks_and_batches_change_no_hash(family, monkeypatch):
+    """`hash_rows` pads the last block and trims it away, and a row's hash
+    string does not depend on the rows hashed alongside it."""
+    from repro.core import lsh
+
+    monkeypatch.setattr(lsh, "HASH_BLOCK_ROWS", 64)
+    fam = make_family(family, jax.random.key(1), 24, 8, w=4.0)
+    x = np.random.default_rng(0).normal(size=(150, 24)).astype(np.float32)
+    whole = np.asarray(lsh.hash_rows(fam, x))
+    assert whole.shape == (150, 8) and whole.dtype == np.int32
+    np.testing.assert_array_equal(whole, np.asarray(fam.hash(jnp.asarray(x))))
+    parts = [np.asarray(lsh.hash_rows(fam, x[lo:lo + 37]))
+             for lo in range(0, 150, 37)]
+    np.testing.assert_array_equal(np.concatenate(parts), whole)
+
+
 def test_theorem51_lambda_sublinear_in_n():
     """lambda/n must shrink as m grows (Theorem 5.1: lambda = O(m^{1-1/rho} n))."""
     p1, p2 = 0.9, 0.5

@@ -89,7 +89,6 @@ def test_grad_compress_int8_psum():
         """
         import numpy as np, jax, jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_debug_mesh
         from repro.optim import compress_psum_int8
@@ -102,10 +101,10 @@ def test_grad_compress_int8_psum():
         def step(grads, err):
             return compress_psum_int8(grads, err, ("data",))
 
-        fn = shard_map(step, mesh=mesh,
-                       in_specs=({"w": P("data", None)}, {"w": P("data", None)}),
-                       out_specs=({"w": P("data", None)}, {"w": P("data", None)}),
-                       check_rep=False)
+        fn = jax.shard_map(step, mesh=mesh,
+                           in_specs=({"w": P("data", None)}, {"w": P("data", None)}),
+                           out_specs=({"w": P("data", None)}, {"w": P("data", None)}),
+                           check_vma=False)
         red, err = fn(grads, err0)
         exact = jnp.mean(g, axis=0)
         # every device row holds the same reduced mean

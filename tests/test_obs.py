@@ -400,6 +400,21 @@ def test_trace_context_manager_exports_and_restores(tmp_path):
     assert [e["name"] for e in evs] == ["inside"]
 
 
+def test_device_profile_raises_when_the_profiler_cannot_start(
+        tmp_path, monkeypatch):
+    """A run that asked for a device trace never silently records none."""
+    import jax
+
+    from repro.obs.trace import device_profile
+
+    def refuse(logdir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        device_profile(tmp_path)
+
+
 def test_obs_package_does_not_shadow_submodules():
     """`repro.obs.trace` the submodule vs `repro.obs.trace` the re-exported
     contextmanager: attribute access on the package must yield the callable

@@ -100,6 +100,40 @@ def test_uneven_split_global_ids_regression():
     assert "UNEVEN-OK" in out
 
 
+def test_shard_csas_built_on_their_own_devices():
+    """Each shard's CSA comes out of the build on the device that holds the
+    shard's rows (one (1, ...) slice per device), equal to `build_csa` over
+    that shard's hash strings alone."""
+    out = _run(
+        """
+        import numpy as np, jax
+        from repro.core import LCCSIndex, build_csa
+        from repro.shard import make_shard_mesh
+
+        X = np.random.default_rng(2).normal(size=(203, 16)).astype(np.float32)
+        mesh = make_shard_mesh(4)
+        sidx = LCCSIndex.build(X, m=8, family="euclidean", w=4.0,
+                               seed=0).shard(mesh)
+        devices = list(mesh.devices.flat)
+        for leaf in jax.tree.leaves(sidx.csa):
+            shards = sorted(leaf.addressable_shards,
+                            key=lambda s: s.index[0].start or 0)
+            assert [s.device for s in shards] == devices
+            assert all(s.data.shape[0] == 1 for s in shards)
+        h = np.asarray(sidx.h)
+        for s in range(4):
+            want = build_csa(jax.numpy.asarray(h[s]))
+            for got, ref in zip(jax.tree.leaves(sidx.csa),
+                                jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(got)[s],
+                                              np.asarray(ref))
+        print("PLACED-OK")
+        """,
+        n_dev=4,
+    )
+    assert "PLACED-OK" in out
+
+
 def test_distributed_query_shim_uneven_n():
     """The deprecated `core.distributed.distributed_query` shim now routes
     through repro.shard and must be exact at n % n_shards != 0."""

@@ -301,8 +301,8 @@ def test_gather_kernel_env_toggle(monkeypatch):
     monkeypatch.setenv("REPRO_GATHER_KERNEL", "0")
     assert resolve_use_kernel(None) is False
     monkeypatch.delenv("REPRO_GATHER_KERNEL")
-    # CPU container: default off (interpret-mode Pallas is correct but slow)
-    assert resolve_use_kernel(None) is (jax.default_backend() == "tpu")
+    # default off on every backend: the gather kernels do not lower for TPU
+    assert resolve_use_kernel(None) is False
 
 
 # -- recall parity property ----------------------------------------------------

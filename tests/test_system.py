@@ -264,3 +264,38 @@ def test_serve_batch_nowait_matches_serve_batch():
     before = engine.stats.snapshot()
     engine.serve_batch_nowait(corpus[:8], p, n_live=3).result()
     assert engine.stats.delta(before).requests == 3
+
+
+def test_compile_cache_env_dir_wins_else_fixed_checkout_dir(monkeypatch,
+                                                            tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` is left to JAX; without it the cache goes
+    to `.jax_cache/` at the checkout's root, the same path on every run."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import ENV_CACHE_DIR, enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(ENV_CACHE_DIR, str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was  # nothing set
+        monkeypatch.delenv(ENV_CACHE_DIR)
+        fixed = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert enable_compile_cache() == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_cpu_benchmark_workers_refuse_an_accelerator_host(monkeypatch):
+    """The figure benchmarks time CPU-only child processes: on a host whose
+    JAX sees an accelerator they refuse rather than time the CPU in its
+    place (or fight the parent for the chip)."""
+    from benchmarks.common import cpu_worker_env
+
+    env = cpu_worker_env(2)
+    assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        cpu_worker_env(2)
