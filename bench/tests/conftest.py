@@ -1,0 +1,11 @@
+"""CPU tests of the benchmark: `PYTHONPATH=src python -m pytest bench/tests`
+(the repository's pytest settings import `repro` before any conftest)."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
