@@ -7,7 +7,7 @@ machine-readably.
 Run: PYTHONPATH=src python -m benchmarks.run [--quick] [--smoke]
   --quick  halve the dataset sizes
   --smoke  fig12 (store sweep) + fig13 (sharded scaling) + fig14 (serving
-           front) + stage breakdown (instrumented plans + BENCH_trace.json)
+           front) + stage breakdown (instrumented plans)
            only, tiny n -- the CI gate; still emits BENCH_search.json
 """
 from __future__ import annotations
@@ -72,7 +72,7 @@ def main() -> None:
             csv, corpus_docs=128, max_batch=8,
             n_bursts=4, burst=20, period_s=0.7, sweep_cap=800
         )
-        print("# trace (smoke): per-stage breakdown + Chrome trace", flush=True)
+        print("# trace (smoke): per-stage breakdown", flush=True)
         search_perf["stage_breakdown"] = stage_breakdown.run(
             csv, n=1000, queries=32, repeats=3
         )
@@ -106,7 +106,7 @@ def main() -> None:
     )
     print("# fig14: serving front -- bursty p99 + replica SLO sweep", flush=True)
     search_perf["serving"] = fig14_serving.run(csv)
-    print("# trace: per-stage breakdown + Chrome trace", flush=True)
+    print("# trace: per-stage breakdown", flush=True)
     search_perf["stage_breakdown"] = stage_breakdown.run(csv)
     print("# table1: complexity scaling in n", flush=True)
     table1_scaling.run(csv)

@@ -9,10 +9,9 @@ tuning knob moves).
 
 Timings come off the registry histogram
 `repro_exec_stage_seconds{topology,stage}` via snapshot/delta -- the exact
-series a Prometheus scrape of a production server exports -- and the run
-also collects the span stream with tracing enabled, writing it as
-``BENCH_trace.json`` (Chrome Trace Event Format: load at ui.perfetto.dev or
-chrome://tracing).
+series a Prometheus scrape of a production server exports.  (A profiler
+trace of a served run, `launch.serve --trace DIR`, shows the same stages on
+the device's clock.)
 
 Sharding needs fake host devices fixed before jax initialises, so `run`
 re-invokes this module as a subprocess with XLA_FLAGS set and parses one
@@ -31,8 +30,8 @@ from .common import CsvRows, cpu_worker_env
 _MARK = "TRACE-JSON:"
 
 
-def run(csv: CsvRows, n: int = 1500, queries: int = 32, repeats: int = 5,
-        trace_path: str = "BENCH_trace.json") -> dict:
+def run(csv: CsvRows, n: int = 1500, queries: int = 32,
+        repeats: int = 5) -> dict:
     """Spawn the measurement subprocess (2 fake devices for the sharded
     topology) and fold per-stage means into csv + the returned payload."""
     env = cpu_worker_env(2)
@@ -40,7 +39,7 @@ def run(csv: CsvRows, n: int = 1500, queries: int = 32, repeats: int = 5,
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.stage_breakdown", "--worker",
          "--n", str(n), "--queries", str(queries),
-         "--repeats", str(repeats), "--trace-path", trace_path],
+         "--repeats", str(repeats)],
         capture_output=True, text=True, timeout=1800, env=env, cwd=root,
     )
     if proc.returncode != 0:
@@ -57,13 +56,12 @@ def run(csv: CsvRows, n: int = 1500, queries: int = 32, repeats: int = 5,
     return payload
 
 
-def _worker(n: int, n_queries: int, repeats: int, trace_path: str) -> dict:
+def _worker(n: int, n_queries: int, repeats: int) -> dict:
     import numpy as np
 
     from repro.core import LCCSIndex, SearchParams
     from repro.exec import execute
     from repro.obs.registry import registry
-    from repro.obs.trace import enable_tracing, export_chrome_trace
     from repro.shard import make_shard_mesh
 
     from benchmarks.common import dataset
@@ -78,7 +76,6 @@ def _worker(n: int, n_queries: int, repeats: int, trace_path: str) -> dict:
         "sharded": mono.shard(make_shard_mesh(2)),
     }
 
-    enable_tracing()  # span stream -> BENCH_trace.json alongside the stats
     topologies: dict[str, dict] = {}
     for topo, idx in indexes.items():
         execute(idx, Q, sp, instrument=True)  # compile outside the window
@@ -103,12 +100,9 @@ def _worker(n: int, n_queries: int, repeats: int, trace_path: str) -> dict:
             }
         topologies[topo] = stages
 
-    doc = export_chrome_trace(trace_path)
     return {
         "n": int(n), "queries": int(n_queries), "repeats": int(repeats),
         "topologies": topologies,
-        "trace_file": trace_path,
-        "trace_events": len(doc["traceEvents"]),
     }
 
 
@@ -120,15 +114,13 @@ def main() -> None:
     ap.add_argument("--n", type=int, default=1500)
     ap.add_argument("--queries", type=int, default=32)
     ap.add_argument("--repeats", type=int, default=5)
-    ap.add_argument("--trace-path", default="BENCH_trace.json")
     args = ap.parse_args()
     if args.worker:
         print(_MARK + json.dumps(
-            _worker(args.n, args.queries, args.repeats, args.trace_path)))
+            _worker(args.n, args.queries, args.repeats)))
         return
     csv = CsvRows()
-    payload = run(csv, n=args.n, queries=args.queries, repeats=args.repeats,
-                  trace_path=args.trace_path)
+    payload = run(csv, n=args.n, queries=args.queries, repeats=args.repeats)
     csv.dump()
     print(json.dumps(payload, indent=2))
 

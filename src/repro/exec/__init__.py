@@ -26,6 +26,7 @@ from .plan import (
     compile_plan,
     execute,
     get_topology,
+    named_jit,
     plan_cache,
     register_topology,
     resolve_params,
@@ -42,6 +43,7 @@ __all__ = [
     "compile_plan",
     "execute",
     "get_topology",
+    "named_jit",
     "plan_cache",
     "register_topology",
     "resolve_params",

@@ -34,6 +34,7 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from functools import partial
 from typing import TYPE_CHECKING, Any, Callable
 
 import jax
@@ -43,6 +44,14 @@ if TYPE_CHECKING:  # pragma: no cover -- leaf module: core imports stay lazy
     from repro.core.params import SearchParams
 
 Runner = Callable[[Any, jax.Array], tuple[jax.Array, jax.Array]]
+
+
+def named_jit(fn: Callable, name: str, **static) -> Callable:
+    """`jax.jit(partial(fn, **static))` whose program is `jit_<name>` in the
+    HLO and in profiler traces (a bare partial compiles as `jit__unknown`)."""
+    f = partial(fn, **static)
+    f.__name__ = name  # type: ignore[attr-defined]
+    return jax.jit(f)
 
 
 # ---------------------------------------------------------------------------

@@ -14,8 +14,6 @@ place -- and nowhere else.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
@@ -24,7 +22,7 @@ from repro.obs.trace import stage as _obs_stage
 from repro.store import tail as tail_mod
 
 from . import stages
-from .plan import register_topology, topology_of
+from .plan import named_jit, register_topology, topology_of
 
 
 # ---------------------------------------------------------------------------
@@ -35,23 +33,32 @@ from .plan import register_topology, topology_of
 def search_pipeline(index, queries: jax.Array, params: SearchParams):
     """hash -> probe -> verify over one resident-data index: the staged form
     of the paper's full query algorithm.  Pure function of a pytree index;
-    `params` must be static under jit."""
-    qh = stages.hash_queries(index.family, queries)
-    cand_ids, _ = stages.probe(index, queries, qh, params)
-    return stages.verify(
-        index.store, index.tail, queries, cand_ids, params,
-        params.metric or index.metric,
-    )
+    `params` must be static under jit.  Each stage runs in a
+    `jax.named_scope` of its name, so a profiler trace attributes device
+    time by stage (scopes are metadata: the compiled ops are the same)."""
+    with jax.named_scope("hash"):
+        qh = stages.hash_queries(index.family, queries)
+    with jax.named_scope("probe"):
+        cand_ids, _ = stages.probe(index, queries, qh, params)
+    with jax.named_scope("verify"):
+        return stages.verify(
+            index.store, index.tail, queries, cand_ids, params,
+            params.metric or index.metric,
+        )
 
 
 def survivor_pipeline(index, queries: jax.Array, params: SearchParams):
     """hash -> probe -> stage-1 survivors only: the jitted front half of the
     disk-tail split plan.  Returns survivor ids (B, R)."""
-    qh = stages.hash_queries(index.family, queries)
-    cand_ids, _ = stages.probe(index, queries, qh, params)
-    surv, _ = stages.survivors(
-        index.store, queries, cand_ids, params, params.metric or index.metric
-    )
+    with jax.named_scope("hash"):
+        qh = stages.hash_queries(index.family, queries)
+    with jax.named_scope("probe"):
+        cand_ids, _ = stages.probe(index, queries, qh, params)
+    with jax.named_scope("survivors"):
+        surv, _ = stages.survivors(
+            index.store, queries, cand_ids, params,
+            params.metric or index.metric
+        )
     return surv
 
 
@@ -96,9 +103,9 @@ def _monolithic_resolve(index, p: SearchParams) -> SearchParams:
 
 def _monolithic_build(index, p: SearchParams):
     if not has_disk_tail(index):
-        return jax.jit(partial(search_pipeline, params=p))
+        return named_jit(search_pipeline, "search_pipeline", params=p)
 
-    stage1 = jax.jit(partial(survivor_pipeline, params=p))
+    stage1 = named_jit(survivor_pipeline, "survivor_pipeline", params=p)
 
     def run(idx, queries):
         # split plan: jitted stage 1 -> host memmap gather -> jitted rerank
@@ -147,11 +154,12 @@ def _monolithic_build_instrumented(index, p: SearchParams):
     metric = p.metric or index.metric
     use_k = stages.resolve_use_kernel(p.use_gather_kernel)
     block = jax.block_until_ready
-    hash_j = jax.jit(stages.hash_queries)
-    probe_j = jax.jit(partial(_probe_ids, params=p))
+    hash_j = named_jit(stages.hash_queries, "hash_queries")
+    probe_j = named_jit(_probe_ids, "probe", params=p)
 
     if has_disk_tail(index):
-        surv_j = jax.jit(partial(_survivor_ids, params=p, metric=metric))
+        surv_j = named_jit(_survivor_ids, "survivors", params=p,
+                           metric=metric)
 
         def run(idx, queries):
             with _obs_stage(topo, "hash_queries"):
@@ -171,9 +179,9 @@ def _monolithic_build_instrumented(index, p: SearchParams):
         return run
 
     if index.store.exact:
-        dist_j = jax.jit(partial(_exact_dists, metric=metric,
-                                 use_kernel=use_k))
-        merge_j = jax.jit(partial(stages.topk_ids, k=p.k))
+        dist_j = named_jit(_exact_dists, "gather", metric=metric,
+                           use_kernel=use_k)
+        merge_j = named_jit(stages.topk_ids, "merge", k=p.k)
 
         def run(idx, queries):
             with _obs_stage(topo, "hash_queries"):
@@ -188,8 +196,9 @@ def _monolithic_build_instrumented(index, p: SearchParams):
 
         return run
 
-    surv_j = jax.jit(partial(_survivors_stage, params=p, metric=metric))
-    gather_j = jax.jit(_gather_rows)
+    surv_j = named_jit(_survivors_stage, "survivors", params=p,
+                       metric=metric)
+    gather_j = named_jit(_gather_rows, "gather")
 
     def run(idx, queries):
         with _obs_stage(topo, "hash_queries"):

@@ -20,9 +20,10 @@ the SLO window: p50/p95/p99 end-to-end latency, deadline misses, queue
 depth, and the per-replica retrace audit.
 
 Observability (repro.obs): --metrics-port N serves Prometheus text format on
-:N/metrics and logs a periodic one-line stats summary; --trace PATH collects
-the request span tree (queue wait, embed, search, exec stages) and writes
-Chrome-trace JSON loadable at https://ui.perfetto.dev; --instrument serves
+:N/metrics and logs a periodic one-line stats summary; --trace DIR records a
+JAX profiler trace of the run (the request spans -- router, engine, exec
+stages -- and the device ops on one clock; `perfetto_trace.json.gz` loads at
+https://ui.perfetto.dev); --instrument serves
 through the staged per-stage-timed plans (bit-identical results);
 --drift-probe N replays N pinned queries against brute-force ground truth
 after serving and reports achieved recall (the recall-drift gauge).
@@ -205,9 +206,11 @@ def main():
     ap.add_argument("--stats-interval", type=float, default=5.0,
                     help="seconds between periodic stats log lines "
                          "(with --metrics-port)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="collect the request span tree and write "
-                         "Chrome-trace JSON here (load at ui.perfetto.dev)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="record a JAX profiler trace of the run into DIR: "
+                         "the request spans and the device ops on one "
+                         "clock (.xplane.pb for TensorBoard/xprof, "
+                         "perfetto_trace.json.gz for ui.perfetto.dev)")
     ap.add_argument("--instrument", action="store_true",
                     help="serve through the staged per-stage-timed plan "
                          "variants: bit-identical results, every exec stage "
@@ -230,7 +233,20 @@ def main():
                  "SegmentedLCCSIndex.ingest_chunks")
     _ensure_devices(args.shards)
     enable_compile_cache()
+    if args.trace:
+        from repro.obs import device_profile
 
+        with device_profile(args.trace):
+            _serve(args)
+        print(f"[launch.serve] wrote a profiler trace of spans and device "
+              f"ops under {args.trace} (TensorBoard/xprof; "
+              f"perfetto_trace.json.gz loads at ui.perfetto.dev)")
+    else:
+        _serve(args)
+
+
+def _serve(args) -> None:
+    """Build, index and serve as `args` say, then the obs epilogue."""
     # any width-vs-lam warning fires once, on the from_legacy construction;
     # the chained field replaces below derive from the same user choice
     from repro.core.params import _suppress_width_warning
@@ -258,7 +274,7 @@ def main():
             params = state.params
             print(f"[launch.serve] restored step {meta['step']} from {args.ckpt_dir}")
 
-    # observability front: metrics endpoint + periodic log line + tracing
+    # observability front: metrics endpoint + periodic log line
     metrics_srv, stats_log = None, None
     if args.metrics_port is not None:
         from repro.obs import StatsLogger, start_metrics_server
@@ -268,10 +284,6 @@ def main():
         print(f"[launch.serve] Prometheus metrics on "
               f":{metrics_srv.port}/metrics "
               f"(stats line every {args.stats_interval:.0f}s)")
-    if args.trace:
-        from repro.obs import enable_tracing
-
-        enable_tracing()
 
     engine = RetrievalEngine(cfg, params, m=args.m, metric="angular",
                              max_batch=args.max_batch,
@@ -344,8 +356,7 @@ def main():
 
 def _obs_epilogue(engine, corpus, args, search_params, metrics_srv,
                   stats_log) -> None:
-    """Post-serve observability: drift probe, Chrome-trace export, metrics
-    teardown."""
+    """Post-serve observability: drift probe, metrics teardown."""
     if args.drift_probe:
         from repro.obs import RecallDriftProbe
 
@@ -357,12 +368,6 @@ def _obs_epilogue(engine, corpus, args, search_params, metrics_srv,
         print(f"[launch.serve] recall-drift probe: "
               f"recall@{search_params.k} = {recall:.3f} over {n} pinned "
               f"queries (gauge repro_recall_drift)")
-    if args.trace:
-        from repro.obs import export_chrome_trace
-
-        doc = export_chrome_trace(args.trace)
-        print(f"[launch.serve] wrote {len(doc['traceEvents'])} trace events "
-              f"to {args.trace} (load at ui.perfetto.dev)")
     if stats_log is not None:
         stats_log.stop()
     if metrics_srv is not None:

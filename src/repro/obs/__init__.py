@@ -6,11 +6,11 @@ One registry, one trace, one export surface (DESIGN.md §8):
                snapshot/delta windowing.  `ServeStats`, `RouterStats`,
                `LatencyWindow`, and the plan cache's scope counters all emit
                here, so "three disconnected stats surfaces" is over.
-    tracing    `span("embed")`-style context managers threaded through
-               Router.submit -> AdmissionQueue -> Replica ->
-               RetrievalEngine.serve_batch -> exec.execute, exported as
-               Chrome-trace JSON (perfetto-loadable); `device_profile()`
-               hooks `jax.profiler.trace` for real-TPU runs.
+    tracing    `span("router.serve")`-style context managers threaded
+               through Router.submit -> Replica -> RetrievalEngine ->
+               exec, recorded as TraceMe events in the JAX profiler's
+               trace, on one clock with the device's operations;
+               `device_profile(logdir)` records one (xplane + perfetto).
     stages     instrumented plan variants (`execute(..., instrument=True)`)
                time every exec stage with `block_until_ready` fences,
                feeding `repro_exec_stage_seconds{topology,stage}`.
@@ -19,9 +19,10 @@ One registry, one trace, one export surface (DESIGN.md §8):
     drift      `RecallDriftProbe` replays a pinned query sample against
                brute-force ground truth and gauges achieved recall.
 
-Everything is opt-in and zero-overhead when off: tracing disabled is a
-single bool check, and un-instrumented plans are byte-for-byte the plans
-this package never touched.
+Everything is opt-in and near zero-overhead when off: a span with no
+profile recording is one TraceMe check, and un-instrumented plans compile
+to the same operations whatever this package records (named scopes are
+metadata).
 """
 from .registry import (
     Counter,
@@ -30,20 +31,7 @@ from .registry import (
     Registry,
     registry,
 )
-from .trace import (
-    add_span,
-    clear_trace,
-    device_profile,
-    disable_tracing,
-    enable_tracing,
-    events,
-    export_chrome_trace,
-    span,
-    stage,
-    to_chrome_trace,
-    trace,
-    tracing_enabled,
-)
+from .trace import device_profile, span, stage
 from .prom import MetricsServer, StatsLogger, render_text, start_metrics_server
 from .drift import RecallDriftProbe, recall_at_k
 
@@ -55,20 +43,11 @@ __all__ = [
     "RecallDriftProbe",
     "Registry",
     "StatsLogger",
-    "add_span",
-    "clear_trace",
     "device_profile",
-    "disable_tracing",
-    "enable_tracing",
-    "events",
-    "export_chrome_trace",
     "recall_at_k",
     "registry",
     "render_text",
     "span",
     "stage",
     "start_metrics_server",
-    "to_chrome_trace",
-    "trace",
-    "tracing_enabled",
 ]

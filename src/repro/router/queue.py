@@ -97,6 +97,7 @@ class Request:
     deadline: float
     t_submit: float
     ticket: Ticket
+    rid: int = -1  # the router's request id (trace spans carry it)
 
     @property
     def shape(self) -> tuple:

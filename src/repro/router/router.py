@@ -32,16 +32,14 @@ Engine protocol (duck-typed so tests can use stubs): `max_batch`, `stats`
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import Counter
 
 import numpy as np
 
-# NOTE: the submodule import path, not `from repro.obs import trace` -- the
-# package re-exports the trace() contextmanager under that name
 from repro.obs.registry import registry
-from repro.obs.trace import add_span as _add_span
 from repro.obs.trace import span as _span
 
 from .metrics import LatencyWindow, ReplicaStats, RouterStats, percentiles_ms
@@ -87,6 +85,10 @@ class Replica:
         self._g_depth = reg.gauge(
             "repro_router_queue_depth", "admitted requests awaiting service",
             labelnames=("replica",))
+        self._h_wait = reg.histogram(
+            "repro_router_queue_wait_seconds",
+            "seconds from submit to the dispatch of the request's batch",
+            labelnames=("replica",))
         # monotonic totals; the window view subtracts the baselines below
         self.finished = 0      # requests that left the worker (ok or failed)
         self.completed = 0     # successfully served
@@ -105,46 +107,52 @@ class Replica:
 
     def _loop(self) -> None:
         eng = self.engine
-        while True:
-            batch = self.queue.next_batch(eng.max_batch,
-                                          linger_s=self.linger_s)
+        for seq in itertools.count():
+            with _span("router.next_batch", replica=self.name):
+                batch = self.queue.next_batch(eng.max_batch,
+                                              linger_s=self.linger_s)
             if batch is None:
                 return  # closed and drained
-            n_live = len(batch)
-            tokens = np.stack([r.tokens for r in batch])
-            if self.pad_batches:
-                tokens = _pad_rows(tokens, eng.max_batch)
-            t0 = time.perf_counter()
-            # retroactive queue-wait span: admission happened on the
-            # submitter's thread, so the wait is only known at batch start
-            _add_span("queue_wait", min(r.t_submit for r in batch), t0,
-                      batch=n_live, replica=self.name)
-            try:
-                pending = eng.serve_batch_nowait(tokens, self.params,
-                                                 n_live=n_live)
-                ids, dists = pending.result()
-            except Exception as exc:
-                for r in batch:
-                    r.ticket._fail(exc)
-                self.finished += n_live
-                continue
-            t_done = time.perf_counter()
-            self.queue.note_service(t_done - t0, n_live)
-            self.hist[n_live] += 1
-            self._h_batch.observe(n_live, replica=self.name)
-            misses = 0
-            for i, r in enumerate(batch):
-                r.ticket._fulfil((ids[i], dists[i]))
-                self.latency.record(t_done - r.t_submit)
-                if t_done > r.deadline:
-                    self.deadline_misses += 1
-                    misses += 1
-            self.completed += n_live
+            with _span("router.serve", replica=self.name, n_live=len(batch),
+                       batch=seq,
+                       reqs=lambda: " ".join(str(r.rid) for r in batch)):
+                self._serve(batch)
+
+    def _serve(self, batch) -> None:
+        eng = self.engine
+        n_live = len(batch)
+        tokens = np.stack([r.tokens for r in batch])
+        if self.pad_batches:
+            tokens = _pad_rows(tokens, eng.max_batch)
+        t0 = time.perf_counter()
+        for r in batch:
+            self._h_wait.observe(t0 - r.t_submit, replica=self.name)
+        try:
+            pending = eng.serve_batch_nowait(tokens, self.params,
+                                             n_live=n_live)
+            ids, dists = pending.result()
+        except Exception as exc:
+            for r in batch:
+                r.ticket._fail(exc)
             self.finished += n_live
-            self._c_completed.inc(n_live, replica=self.name)
-            if misses:
-                self._c_misses.inc(misses, replica=self.name)
-            self._g_depth.set(self.queue.depth(), replica=self.name)
+            return
+        t_done = time.perf_counter()
+        self.queue.note_service(t_done - t0, n_live)
+        self.hist[n_live] += 1
+        self._h_batch.observe(n_live, replica=self.name)
+        misses = 0
+        for i, r in enumerate(batch):
+            r.ticket._fulfil((ids[i], dists[i]))
+            self.latency.record(t_done - r.t_submit)
+            if t_done > r.deadline:
+                self.deadline_misses += 1
+                misses += 1
+        self.completed += n_live
+        self.finished += n_live
+        self._c_completed.inc(n_live, replica=self.name)
+        if misses:
+            self._c_misses.inc(misses, replica=self.name)
+        self._g_depth.set(self.queue.depth(), replica=self.name)
 
     def reset_window(self) -> None:
         self.latency.clear()
@@ -193,6 +201,7 @@ class Router:
         self._b_admitted = 0  # guarded-by: _lock
         self._b_rejected = 0  # guarded-by: _lock
         self._rr = 0  # guarded-by: _lock
+        self._next_rid = 0  # guarded-by: _lock (request ids, for spans)
         self._shutdown = False  # guarded-by: _lock
         reg = registry()
         self._c_admitted = reg.counter(
@@ -244,6 +253,8 @@ class Router:
         with self._lock:
             if self._shutdown:
                 raise RuntimeError("router is shut down")
+            rid = self._next_rid
+            self._next_rid += 1
         tokens = np.asarray(tokens)
         if tokens.ndim != 1:
             raise ValueError(
@@ -252,7 +263,7 @@ class Router:
             )
         now = time.perf_counter()
         slo_ms = self.default_slo_ms if deadline_ms is None else deadline_ms
-        with _span("router.submit"):
+        with _span("router.submit", req=rid):
             depths = [r.queue.depth() for r in self.replicas]
             best = min(depths)
             cands = [i for i, d in enumerate(depths) if d == best]
@@ -263,7 +274,7 @@ class Router:
             ticket = Ticket(now + slo_ms / 1e3, now, replica.name)
             try:
                 replica.queue.offer(
-                    Request(tokens, ticket.deadline, now, ticket))
+                    Request(tokens, ticket.deadline, now, ticket, rid))
             except QueueFull:
                 with self._lock:
                     self._rejected += 1

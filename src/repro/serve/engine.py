@@ -35,7 +35,6 @@ from repro.compat import ReproDeprecationWarning
 from repro.core import LCCSIndex, SearchParams, SegmentedLCCSIndex
 from repro.exec import compile_plan, plan_cache
 from repro.models import lm
-from repro.obs.trace import add_span as _add_span
 from repro.obs.trace import span as _span
 from repro.obs.registry import registry
 from repro.shard import make_shard_mesh
@@ -108,18 +107,15 @@ class PendingBatch:
     def result(self) -> tuple[np.ndarray, np.ndarray]:
         if self._out is not None:
             return self._out
-        jax.block_until_ready(self._q_emb)
-        t1 = time.perf_counter()
-        jax.block_until_ready(self._dists)
-        t2 = time.perf_counter()
+        with _span("engine.wait"):
+            jax.block_until_ready(self._q_emb)
+            t1 = time.perf_counter()
+            jax.block_until_ready(self._dists)
+            t2 = time.perf_counter()
         self._engine._record_serve(self._n_live, t1 - self._t0,
                                    max(t2 - t1, 0.0), self._hit)
-        # retroactive spans: the embed/search device drains happened between
-        # dispatch (t0) and now, on whatever thread called result()
-        _add_span("serve_batch", self._t0, t2, batch=self._n_live)
-        _add_span("embed", self._t0, t1)
-        _add_span("search", t1, t2)
-        self._out = (np.asarray(self._ids), np.asarray(self._dists))
+        with _span("engine.to_host"):
+            self._out = (np.asarray(self._ids), np.asarray(self._dists))
         return self._out
 
 
@@ -329,10 +325,13 @@ class RetrievalEngine:
         assert self.index is not None, "build_index first"
         p = self._resolve_params(params, {})
         t0 = time.perf_counter()
-        q_emb = self.embed(query_tokens)
-        plan, hit = compile_plan(self.index, q_emb, p, return_hit=True,
-                                 scope=self.name, instrument=self.instrument)
-        ids, dists = plan.run(self.index, jnp.asarray(q_emb, jnp.float32))
+        with _span("engine.dispatch"):
+            q_emb = self.embed(query_tokens)
+            plan, hit = compile_plan(self.index, q_emb, p, return_hit=True,
+                                     scope=self.name,
+                                     instrument=self.instrument)
+            ids, dists = plan.run(self.index,
+                                  jnp.asarray(q_emb, jnp.float32))
         n = query_tokens.shape[0] if n_live is None else n_live
         return PendingBatch(self, q_emb, ids, dists, n, hit, t0)
 

@@ -52,7 +52,8 @@ from repro.core.csa import CSA
 from repro.core.index import LCCSIndex
 from repro.core.params import SearchParams, _suppress_width_warning
 from repro.core.sources import get_source, register_source
-from repro.exec import execute as _execute, register_topology, stages
+from repro.exec import execute as _execute, named_jit, register_topology
+from repro.exec import stages
 
 from .index import ShardedLCCSIndex, _row_spec
 
@@ -180,15 +181,19 @@ def _merge_global(parts, queries, params, metric, exact: bool):
 def _local_search(family, store, h, csa, gid, tail, queries, qh,
                   *, params, metric, axis, shards):
     view, gid_l = _local_view(family, store, h, csa, gid, tail, metric)
-    ids_l, g = _probe_local(view, gid_l, queries, qh, params, shards)
-    parts = _verify_local(view, gid_l, ids_l, g, queries, params, metric,
-                          shards)
-    B = queries.shape[0]
-    pool = lambda x: jax.lax.all_gather(x, axis, axis=1).reshape(
-        (B, -1) + x.shape[2:]
-    )
-    return _merge_global(tuple(pool(x) for x in parts), queries, params,
-                         metric, view.store.exact)
+    with jax.named_scope("probe"):
+        ids_l, g = _probe_local(view, gid_l, queries, qh, params, shards)
+    # verify spans the per-shard scan and the global merge, as the
+    # monolithic verify spans its scan and its top-k
+    with jax.named_scope("verify"):
+        parts = _verify_local(view, gid_l, ids_l, g, queries, params,
+                              metric, shards)
+        B = queries.shape[0]
+        pool = lambda x: jax.lax.all_gather(x, axis, axis=1).reshape(
+            (B, -1) + x.shape[2:]
+        )
+        return _merge_global(tuple(pool(x) for x in parts), queries, params,
+                             metric, view.store.exact)
 
 
 def _search_impl(index: ShardedLCCSIndex, queries: jax.Array,
@@ -196,7 +201,8 @@ def _search_impl(index: ShardedLCCSIndex, queries: jax.Array,
     """The traced sharded pipeline body (no guards): hash once, shard_map the
     per-shard stages, merge globally."""
     queries = jnp.asarray(queries, jnp.float32)
-    qh = stages.hash_queries(index.family, queries)
+    with jax.named_scope("hash"):
+        qh = stages.hash_queries(index.family, queries)
     metric = params.metric or index.metric
     fn = _shard_call(
         index,
@@ -260,7 +266,7 @@ def _sharded_resolve(index, p: SearchParams) -> SearchParams:
 
 
 def _sharded_build(index, p: SearchParams):
-    return jax.jit(partial(_search_impl, params=p))
+    return named_jit(_search_impl, "search_pipeline", params=p)
 
 
 # -- instrumented (staged) variant -----------------------------------------
@@ -311,30 +317,29 @@ def _sharded_build_instrumented(index, p: SearchParams):
     block = jax.block_until_ready
     col = P(None, axis)  # (B, S*x) pooled along axis 1, mesh device order
 
-    hash_j = jax.jit(stages.hash_queries)
+    hash_j = named_jit(stages.hash_queries, "hash_queries")
 
     def probe_local(family, store, h, csa, gid, tail, queries, qh):
         view, gid_l = _local_view(family, store, h, csa, gid, tail, metric)
         return _probe_local(view, gid_l, queries, qh, p, shards)
 
-    probe_j = jax.jit(_shard_call_staged(
+    probe_j = named_jit(_shard_call_staged(
         index, probe_local, out_specs=(col, col), extra_in_specs=(P(),)
-    ))
+    ), "probe")
 
     def verify_local(family, store, h, csa, gid, tail, queries, ids_l, g):
         view, gid_l = _local_view(family, store, h, csa, gid, tail, metric)
         return _verify_local(view, gid_l, ids_l, g, queries, p, metric,
                              shards)
 
-    verify_j = jax.jit(_shard_call_staged(
+    verify_j = named_jit(_shard_call_staged(
         index, verify_local,
         out_specs=(col, col) if exact else (col, col, col),
         extra_in_specs=(col, col),
-    ))
+    ), "verify")
 
-    merge_j = jax.jit(lambda parts, queries: _merge_global(
-        parts, queries, p, metric, exact
-    ))
+    merge_j = named_jit(_merge_global, "merge", params=p, metric=metric,
+                        exact=exact)
 
     def run(idx, queries):
         with _obs_stage("sharded", "hash_queries"):

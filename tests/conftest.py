@@ -1,7 +1,5 @@
 import os
-import subprocess
 import sys
-import textwrap
 from pathlib import Path
 
 # Make `repro` importable regardless of how pytest is invoked.
@@ -13,26 +11,3 @@ if str(SRC) not in sys.path:
 # sets 4 fake CPU host devices); the 512-device fake platform is dryrun.py-
 # only per the launch contract.  Keep matmul determinism on.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-
-def run_multidevice(script: str, n_dev: int) -> str:
-    """Run `script` in a subprocess with n_dev fake CPU host devices -- THE
-    multi-device launch recipe (XLA_FLAGS must be set before jax initialises,
-    so multi-device semantics tests cannot run in this process).  Asserts the
-    script exits 0 and returns its stdout."""
-    code = textwrap.dedent(script)
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True,
-        text=True,
-        timeout=600,
-        env={
-            "XLA_FLAGS": f"--xla_force_host_platform_device_count={n_dev}",
-            "PYTHONPATH": str(SRC),
-            "PATH": "/usr/bin:/bin",
-            "JAX_PLATFORMS": "cpu",
-            "HOME": "/tmp",
-        },
-    )
-    assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
-    return proc.stdout

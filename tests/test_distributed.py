@@ -4,7 +4,7 @@ conftest.run_multidevice) so the main test process keeps the invoking
 environment's device view (launch contract)."""
 import pytest
 
-from conftest import run_multidevice
+from multidevice import run_multidevice
 
 
 def _run(script: str, n_dev: int = 8) -> str:

@@ -11,11 +11,10 @@ The load-bearing contracts:
   - instrumented plans return BIT-IDENTICAL (ids, dists) to the fused plans
     for every topology x store x probe-kernel toggle, live under distinct
     cache keys, and leave the fast path's miss audit untouched;
-  - the span stream exports as valid Chrome-trace JSON;
+  - exec stages time into their histogram whether or not a profile records;
   - the /metrics endpoint serves parseable Prometheus text format;
   - the recall-drift probe gauges achieved recall against brute force.
 """
-import json
 import re
 import threading
 import urllib.request
@@ -26,18 +25,7 @@ import pytest
 from repro.core import LCCSIndex, SearchParams, SegmentedLCCSIndex
 from repro.exec import compile_plan, execute, plan_cache
 from repro.obs.registry import registry
-from repro.obs import trace as _trace_mod  # noqa: F401 -- see import test
-from repro.obs.trace import (
-    add_span,
-    clear_trace,
-    disable_tracing,
-    enable_tracing,
-    events,
-    export_chrome_trace,
-    span,
-    stage,
-    tracing_enabled,
-)
+from repro.obs.trace import stage
 
 N, D, B = 160, 16, 4
 # complete-coverage regime (cf. tests/test_exec.py): candidate sets provably
@@ -52,13 +40,6 @@ def data():
     X = rng.normal(size=(N, D)).astype(np.float32)
     Q = rng.normal(size=(B, D)).astype(np.float32)
     return X, Q
-
-
-@pytest.fixture(autouse=True)
-def _tracing_off():
-    yield
-    disable_tracing()
-    clear_trace()
 
 
 # ---------------------------------------------------------------------------
@@ -263,7 +244,7 @@ def test_instrumented_parity_disk_tail(data, tmp_path):
 def test_instrumented_parity_sharded_multidevice(data):
     """Real shard_map staging (4 fake devices): the staged probe/verify/merge
     plan must match the fused all_gather pipeline exactly."""
-    from conftest import run_multidevice
+    from multidevice import run_multidevice
 
     out = run_multidevice(
         """
@@ -338,66 +319,21 @@ def test_instrumented_execute_feeds_stage_histogram(data):
 
 
 # ---------------------------------------------------------------------------
-# Tracing: span tree -> Chrome-trace JSON
+# Tracing: spans and stages on the profiler (tests/test_profiler_spans.py
+# records a whole served trace)
 # ---------------------------------------------------------------------------
 
 
-def test_span_noop_when_disabled():
-    clear_trace()
-    assert not tracing_enabled()
-    with span("invisible"):
-        pass
-    add_span("also-invisible", 0.0, 1.0)
-    assert events() == []
-
-
-def test_span_tree_exports_valid_chrome_trace(tmp_path):
-    enable_tracing()
-    with span("outer", layer="test"):
-        with span("inner"):
-            pass
-    disable_tracing()
-    evs = events()
-    names = [e["name"] for e in evs]
-    assert names == ["inner", "outer"]  # completion order; viewer nests by ts
-    inner, outer = evs
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
-    assert outer["args"] == {"layer": "test"}
-    assert inner["tid"] == outer["tid"]  # same-thread: containment == nesting
-
-    path = tmp_path / "trace.json"
-    doc = export_chrome_trace(path)
-    loaded = json.loads(path.read_text())
-    assert loaded == json.loads(json.dumps(doc))
-    assert loaded["displayTimeUnit"] == "ms"
-    for e in loaded["traceEvents"]:
-        assert e["ph"] == "X"
-        assert {"name", "ts", "dur", "pid", "tid"} <= set(e)
-
-
 def test_stage_times_histogram_even_without_tracing():
-    assert not tracing_enabled()
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled()
     before = registry().get("repro_exec_stage_seconds").count(
         topology="obs-test", stage="probe")
     with stage("obs-test", "probe"):
         pass
     hist = registry().get("repro_exec_stage_seconds")
     assert hist.count(topology="obs-test", stage="probe") == before + 1
-    assert events() == []  # ...but no trace event while disabled
-
-
-def test_trace_context_manager_exports_and_restores(tmp_path):
-    from repro.obs.trace import trace
-
-    path = tmp_path / "ctx_trace.json"
-    assert not tracing_enabled()
-    with trace(path):
-        with span("inside"):
-            pass
-    assert not tracing_enabled()
-    evs = json.loads(path.read_text())["traceEvents"]
-    assert [e["name"] for e in evs] == ["inside"]
 
 
 def test_device_profile_raises_when_the_profiler_cannot_start(
@@ -407,7 +343,7 @@ def test_device_profile_raises_when_the_profiler_cannot_start(
 
     from repro.obs.trace import device_profile
 
-    def refuse(logdir):
+    def refuse(logdir, **kw):
         raise RuntimeError("profiler unavailable")
 
     monkeypatch.setattr(jax.profiler, "trace", refuse)
@@ -416,18 +352,16 @@ def test_device_profile_raises_when_the_profiler_cannot_start(
 
 
 def test_obs_package_does_not_shadow_submodules():
-    """`repro.obs.trace` the submodule vs `repro.obs.trace` the re-exported
-    contextmanager: attribute access on the package must yield the callable
-    (API), while `import repro.obs.trace` yields the module -- consumers
-    import through the submodule path.  Pin both so a refactor cannot
-    silently swap them."""
+    """The package re-exports nothing under a submodule's name: attribute
+    access `repro.obs.trace` and `import repro.obs.trace` yield the same
+    module, and the package's tracing API is the module's."""
     import importlib
 
     import repro.obs as obs
 
-    assert callable(obs.trace)  # the contextmanager re-export wins on attr
     mod = importlib.import_module("repro.obs.trace")
-    assert hasattr(mod, "span") and hasattr(mod, "add_span")
+    assert obs.trace is mod
+    assert obs.span is mod.span and obs.device_profile is mod.device_profile
 
 
 # ---------------------------------------------------------------------------

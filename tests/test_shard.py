@@ -9,7 +9,7 @@ own device view (launch contract); single-shard API tests run in-process
 import numpy as np
 import pytest
 
-from conftest import run_multidevice
+from multidevice import run_multidevice
 
 
 def _run(script: str, n_dev: int = 4) -> str:

@@ -106,7 +106,7 @@ def test_serve_stream_ragged_query_lengths():
 def test_serve_sharded_matches_monolithic():
     """shards=2: the engine partitions the index over two (fake) devices and
     serve_batch answers identically to the monolithic engine."""
-    from conftest import run_multidevice
+    from multidevice import run_multidevice
 
     out = run_multidevice(
         """
