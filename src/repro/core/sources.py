@@ -155,21 +155,21 @@ def multiprobe_full_source(index, queries, qh, params):
     strings, _, _ = _probe_batch(index, queries, qh, params)
     B, P, m = strings.shape
     if params.mode == "parallel" and _fused_probe(index, params):
-        # fused: raw windows of every (probe, shift), ONE scatter-max dedupe
-        # per query over the whole P*m*2W pool.  Equals the legacy two-level
-        # (per-probe top-lam, then merged top-lam) dedupe exactly: any id cut
-        # by its best probe's inner top-lam is outranked by >= lam ids whose
-        # merged values only grow, so it cannot enter the global top-lam.
+        # fused: raw windows of every (probe, shift), ONE dedupe per query
+        # over the whole P*m*2W pool (one two-key sort of the pool).
+        # Equals the legacy two-level (per-probe top-lam, then merged
+        # top-lam) dedupe exactly: any id cut by its best probe's inner
+        # top-lam is outranked by >= lam ids whose merged values only grow,
+        # so it cannot enter the global top-lam.
         from repro.kernels.csa_probe import (
-            csa_probe_windows, dedupe_topk_scatter,
+            csa_probe_windows, dedupe_topk_pool,
         )
 
         w_ids, w_lcps = csa_probe_windows(
             index.csa, strings.reshape(B * P, m), width=width,
         )
-        return dedupe_topk_scatter(
-            w_ids.reshape(B, -1), w_lcps.reshape(B, -1), index.csa.n,
-            params.lam,
+        return dedupe_topk_pool(
+            w_ids.reshape(B, -1), w_lcps.reshape(B, -1), params.lam
         )
     ids, lcps = klccs_search(
         index.csa, strings.reshape(B * P, m), params.lam, width=width,
@@ -198,12 +198,13 @@ def multiprobe_skip_source(index, queries, qh, params):
     fused = _fused_probe(index, params)
     if fused:
         from repro.kernels.csa_probe import (
-            csa_probe_pairs, csa_probe_windows, dedupe_topk_scatter,
+            csa_probe_pairs, csa_probe_windows, dedupe_topk_pool,
         )
 
-        # raw base windows: the scatter-max merge below dedupes the whole
-        # pool at once, so no intermediate top-lam cut is needed (and the
-        # per-shift max of the window LCPs IS the §4.2 len bound)
+        # raw base windows: the merge below dedupes the whole pool at once
+        # (one two-key sort of the pool), so no intermediate top-lam cut is
+        # needed (and the per-shift max of the window LCPs IS the §4.2 len
+        # bound)
         w_ids, w_lcps = csa_probe_windows(index.csa, qh, width=width)
         B0 = qh.shape[0]
         base_ids = w_ids.reshape(B0, -1)
@@ -260,5 +261,5 @@ def multiprobe_skip_source(index, queries, qh, params):
     ids = jnp.concatenate([base_ids, p_ids.reshape(B, -1)], axis=1)
     lcps = jnp.concatenate([base_lcps, p_lcps.reshape(B, -1)], axis=1)
     if fused:
-        return dedupe_topk_scatter(ids, lcps, index.csa.n, params.lam)
+        return dedupe_topk_pool(ids, lcps, params.lam)
     return jax.vmap(lambda i, l: dedupe_topk(i, l, params.lam))(ids, lcps)

@@ -16,6 +16,10 @@ Pallas kernel's (1, n) / (n, 2m) blocks are refused by the TPU lowering,
 and its VMEM-resident Hd bounds it at n <= ~32k for m=64 besides, so no
 size selects it.  Requires a CSA built with the adjacent-LCP table
 (`csa.L`); `supports(csa)` gates that.
+
+Both search wrappers dedupe each query's (m * 2W)-slot pool with
+`ref.dedupe_topk_pool`: one two-key sort over the pool, never a pass over
+all n ids.
 """
 from __future__ import annotations
 
@@ -26,7 +30,7 @@ import jax.numpy as jnp
 
 from ..common import default_interpret
 from .csa_probe import csa_probe_pallas
-from .ref import dedupe_topk_scatter, probe_pairs_ref, search_windows_ref
+from .ref import dedupe_topk_pool, probe_pairs_ref, search_windows_ref
 
 
 def supports(csa) -> bool:
@@ -47,7 +51,7 @@ def _windows(csa, qd, shifts, qidx, width: int, use_pallas: bool):
 @functools.partial(jax.jit, static_argnames=("width", "use_pallas"))
 def csa_probe_windows(csa, q_hash, width: int = 16, use_pallas: bool = False):
     """Raw fused windows of every (query, shift) pair -- the undeduped pool
-    the multiprobe sources merge in one scatter pass.
+    the multiprobe sources merge in one `dedupe_topk_pool` pass.
     q_hash: (B, m) int32.  Returns (ids (B, m, 2W), lcps (B, m, 2W))."""
     B, m = q_hash.shape
     qd = jnp.concatenate([q_hash, q_hash], axis=1).astype(jnp.int32)
@@ -72,9 +76,7 @@ def csa_probe_search(csa, q_hash, lam: int, width: int = 16,
         ids, lcps = _windows(csa, qd, shifts, qidx, width, True)
     else:
         ids, lcps = search_windows_ref(csa, qd, width)
-    return dedupe_topk_scatter(
-        ids.reshape(B, -1), lcps.reshape(B, -1), csa.n, lam
-    )
+    return dedupe_topk_pool(ids.reshape(B, -1), lcps.reshape(B, -1), lam)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "width", "use_pallas"))
@@ -92,8 +94,8 @@ def csa_probe_search_with_lens(csa, q_hash, lam: int, width: int = 16,
     else:
         ids, lcps = search_windows_ref(csa, qd, width)
     maxlen = jnp.max(lcps, axis=2)
-    out_ids, out_lcps = dedupe_topk_scatter(
-        ids.reshape(B, -1), lcps.reshape(B, -1), csa.n, lam
+    out_ids, out_lcps = dedupe_topk_pool(
+        ids.reshape(B, -1), lcps.reshape(B, -1), lam
     )
     return out_ids, out_lcps, maxlen
 

@@ -14,12 +14,15 @@ entries walking away from the boundary (Fact 3.2 monotonicity is exactly this
 chain).  Per (query, shift) the traffic drops to two m-word rows + 2W small
 ints -- a ~W-fold cut -- and the output is bit-identical to `_window`.
 
-Deduplication drops the two stable argsorts of `core.search.dedupe_topk` for
-a scatter-max into an (n,)-slot buffer followed by one `top_k`:
-`buf[id] = max(lcp)` then top-lam over the buffer.  Ties break toward the
-smaller id in both forms (top_k prefers lower indices, and the buffer is
-indexed by id), so the result -- ids, values, *and* order -- matches
-`dedupe_topk` exactly; see tests/test_probe_kernel.py.
+Deduplication (max LCP per id, then the top lam ids) of a (B, P) pool of
+(id, lcp) pairs works over the pool itself: one two-key sort of each row (id
+ascending, lcp descending), keep the first slot of each id run, one `top_k`
+over the P slots -- O(P log P) per row, whatever the index's n.  (The sort
+is over the pool, not over an (n,)-slot buffer: at n = 10^6 and P = m * 2W
+= 8192 such a buffer is ~122x wider than the pool it dedupes.)  Ties break
+toward the smaller id (top_k prefers lower slots, and the pool is sorted by
+id), so the result -- ids, values, *and* order -- matches
+`core.search.dedupe_topk` exactly; see tests/test_probe_kernel.py.
 """
 from __future__ import annotations
 
@@ -119,20 +122,26 @@ def search_windows_ref(csa, qd: jax.Array, width: int):
     return jax.vmap(oneq)(qd)
 
 
-@partial(jax.jit, static_argnames=("n", "lam"))
-def dedupe_topk_scatter(ids: jax.Array, lcps: jax.Array, n: int, lam: int):
-    """Max-LCP per id + global top-lam via scatter-max into an (n,) buffer.
-    Bit-identical to `core.search.dedupe_topk` (set, values, and order) but
-    O(pool + n log lam) instead of two O(pool log pool) stable argsorts.
-    ids/lcps: (B, pool); -1-padded slots are dropped."""
-    safe = jnp.where(ids >= 0, ids, n)  # -1 padding -> OOB slot n -> dropped
-    buf = jnp.full((ids.shape[0], n), -1, jnp.int32)
-    buf = buf.at[jnp.arange(ids.shape[0])[:, None], safe].max(
-        lcps.astype(jnp.int32), mode="drop"
+@partial(jax.jit, static_argnames=("lam",))
+def dedupe_topk_pool(ids: jax.Array, lcps: jax.Array, lam: int):
+    """Max-LCP per id + top-lam of each row of a (B, pool) pool: one two-key
+    sort per row (id ascending, lcp descending), the first slot of each id
+    run holds that id's max LCP, then `top_k` over the pool's slots.  No
+    packed key, so no overflow at any n.  Bit-identical to
+    `core.search.dedupe_topk` per row.
+    ids/lcps: (B, pool); -1-padded slots are dropped.
+    Returns (ids (B, lam), lcps (B, lam)), -1-padded."""
+    sids, neg = lax.sort(
+        (ids.astype(jnp.int32), -lcps.astype(jnp.int32)), dimension=1,
+        num_keys=2,
     )
-    k = min(lam, n)
-    vals, idx = lax.top_k(buf, k)  # ties -> lower id first, as dedupe_topk
-    out_ids = jnp.where(vals >= 0, idx.astype(jnp.int32), -1)
+    first = jnp.concatenate(
+        [jnp.ones_like(sids[:, :1], bool), sids[:, 1:] != sids[:, :-1]], axis=1
+    )
+    score = jnp.where(first & (sids >= 0), -neg, -1)
+    k = min(lam, ids.shape[1])
+    vals, idx = lax.top_k(score, k)  # ties -> lower slot, i.e. lower id
+    out_ids = jnp.where(vals >= 0, jnp.take_along_axis(sids, idx, axis=1), -1)
     if k < lam:  # pad to static lam
         out_ids = jnp.pad(out_ids, ((0, 0), (0, lam - k)), constant_values=-1)
         vals = jnp.pad(vals, ((0, 0), (0, lam - k)), constant_values=-1)

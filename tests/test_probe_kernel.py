@@ -24,7 +24,7 @@ from repro.kernels.csa_probe import (
     csa_probe_pairs,
     csa_probe_search,
     csa_probe_search_with_lens,
-    dedupe_topk_scatter,
+    dedupe_topk_pool,
     supports,
 )
 from repro.kernels.csa_probe.csa_probe import csa_probe_pallas
@@ -92,22 +92,41 @@ def test_fused_pairs_matches_legacy(n, m, width):
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
-def test_dedupe_scatter_matches_dedupe_topk():
-    """One scatter-max pass == the legacy sort-based dedupe: same id set,
-    same values, same tie order (smaller id first on equal LCP)."""
-    n, lam = 53, 12
-    for trial in range(5):
-        rng = np.random.default_rng(trial)
-        ids = rng.integers(-1, n, (4, 40)).astype(np.int32)
-        lcps = np.where(ids >= 0, rng.integers(0, 9, (4, 40)), -1).astype(
-            np.int32
-        )
-        want = jax.vmap(lambda i, l: dedupe_topk(i, l, lam))(
-            jnp.asarray(ids), jnp.asarray(lcps)
-        )
-        got = dedupe_topk_scatter(jnp.asarray(ids), jnp.asarray(lcps), n, lam)
-        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
-        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+def _pool(seed, B, P, n, n_ids=None, max_lcp=9, dead_rows=()):
+    """(B, P) pool of ids in [-1, n_ids) (-1 = padding, lcp -1), rows in
+    `dead_rows` all padding."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(-1, n if n_ids is None else n_ids, (B, P))
+    ids[list(dead_rows)] = -1
+    lcps = np.where(ids >= 0, rng.integers(0, max_lcp, (B, P)), -1)
+    return ids.astype(np.int32), lcps.astype(np.int32)
+
+
+@pytest.mark.parametrize(
+    "seed,B,P,n,lam,pool_kw",
+    [
+        *[(t, 4, 40, 53, 12, {}) for t in range(5)],  # P < n, as before
+        (5, 3, 64, 20, 12, {}),                 # P >= n
+        (6, 2, 30, 5, 8, {}),                   # lam > n
+        (7, 3, 8, 500, 16, {}),                 # P < lam (padded output)
+        (8, 4, 50, 300, 10, dict(dead_rows=(0, 2))),  # all -1 rows
+        # heavy duplicate ids with tied LCPs
+        (9, 4, 200, 1000, 10, dict(n_ids=6, max_lcp=3)),
+        (10, 2, 600, 3000, 300, dict(n_ids=40, max_lcp=2)),
+        # ids near the int32 limit: no packed key, so nothing overflows
+        (11, 3, 100, 2**31 - 1, 20, dict(max_lcp=4)),
+        (12, 1, 1, 1, 3, {}),                   # one slot, one id
+    ],
+)
+def test_dedupe_scatter_matches_dedupe_topk(seed, B, P, n, lam, pool_kw):
+    """The fused probe's pool dedupe == the legacy sort-based dedupe: same
+    id set, same values, same tie order (smaller id first on equal LCP), at
+    every pool width, P < n and P >= n alike."""
+    ids, lcps = map(jnp.asarray, _pool(seed, B, P, n, **pool_kw))
+    want = jax.vmap(lambda i, l: dedupe_topk(i, l, lam))(ids, lcps)
+    got = dedupe_topk_pool(ids, lcps, lam)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
 # ---------------------------------------------------------------------------
@@ -137,9 +156,9 @@ def test_pallas_interpret_matches_ref(n, m, width):
 _SOURCES = ("lccs", "multiprobe-full", "multiprobe-skip")
 
 
-def _toggle_params(source, lam=32, **kw):
+def _toggle_params(source, lam=32, width=16, **kw):
     return SearchParams(
-        k=5, lam=lam, width=16, source=source,
+        k=5, lam=lam, width=width, source=source,
         probes=4 if source.startswith("multiprobe") else 1,
         use_gather_kernel=False, **kw,
     )
@@ -147,10 +166,26 @@ def _toggle_params(source, lam=32, **kw):
 
 @pytest.mark.parametrize("source", _SOURCES)
 def test_toggle_parity_monolithic(source):
+    # every source's fused pool (>= 16 * 2 * 16 = 512 slots) is wider than
+    # n = 150
     X, idx = _index(150, 16, seed=1)
     Q = np.random.default_rng(2).normal(size=(6, 12)).astype(np.float32)
     off = execute(idx, Q, _toggle_params(source, use_probe_kernel=False))
     on = execute(idx, Q, _toggle_params(source, use_probe_kernel=True))
+    np.testing.assert_array_equal(np.asarray(on[0]), np.asarray(off[0]))
+    np.testing.assert_array_equal(np.asarray(on[1]), np.asarray(off[1]))
+
+
+@pytest.mark.parametrize("source", _SOURCES)
+def test_toggle_parity_monolithic_pool_form(source):
+    # every source's fused pool (at most 4 * 8 * 2 * 4 = 256 slots) is
+    # narrower than n = 2000
+    X, idx = _index(2000, 8, seed=1)
+    Q = np.random.default_rng(2).normal(size=(6, 12)).astype(np.float32)
+    off = execute(idx, Q, _toggle_params(source, width=4,
+                                         use_probe_kernel=False))
+    on = execute(idx, Q, _toggle_params(source, width=4,
+                                        use_probe_kernel=True))
     np.testing.assert_array_equal(np.asarray(on[0]), np.asarray(off[0]))
     np.testing.assert_array_equal(np.asarray(on[1]), np.asarray(off[1]))
 

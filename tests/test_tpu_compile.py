@@ -112,7 +112,10 @@ def test_search_plan_compiles_for_one_chip(store, source, one_chip,
     assert p.use_probe_kernel is True and p.use_gather_kernel is False
     compiled = plan.lower(index, _shape((B, D), f32, one_chip)).compile()
     _fits(compiled)
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # the probe dedupes each query's pool by sorting it: no (B, n) buffer
+    assert f"s32[{B},{N}]" not in text
 
 
 def test_bruteforce_topk_fits_one_chip(one_chip):
