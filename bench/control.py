@@ -56,20 +56,22 @@ def main() -> int:
         return 2
     for seed in (int(s) for s in args.seeds.split(",")):
         stand, X = stand_up(cell, seed, args.n)
-        answers = {}
-        for variant in variants:
-            router = serve(stand, X, variant)
-            try:
-                answers[variant] = drive(router, stand, args.seconds,
-                                         seed).answers
-            finally:
-                router.shutdown(drain=False)
-            del router
+        with stand:  # removes a disk tail once the answers are compared
+            answers = {}
+            for variant in variants:
+                router = serve(stand, X, variant)
+                try:
+                    answers[variant] = drive(router, stand, args.seconds,
+                                             seed).answers
+                finally:
+                    router.shutdown(drain=False)
+                del router
+                gc.collect()
+            del X
+            stand.index = None
             gc.collect()
-        del X
-        stand.index = None
-        gc.collect()
-        for variant, nums in check(stand, answers).items():
+            checked = check(stand, answers)
+        for variant, nums in checked.items():
             row = {"cell": cell.name, "seed": seed, "variant": variant,
                    "answers": len(answers[variant]), **nums}
             log(" ".join(f"{k}={v!r}" for k, v in row.items()))

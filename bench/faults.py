@@ -6,7 +6,11 @@ CPU tests in `bench/tests/` check that each one turns `correct` false.
 
 control       the program's own lower-precision path in place of the
               configured one: the fp32 store becomes a bf16 store, so
-              verify ranks and reports distances on bf16 rows.
+              verify ranks and reports distances on bf16 rows.  An
+              inexact store (int8, bf16) keeps its store, and its fp32
+              rerank rows are rounded to bf16: in place for a device tail,
+              as a second tail file beside the first for a disk tail.  So
+              the rerank reports distances on bf16 rows.
 stale         a batch returns the previous batch's answers: a step that
               returns its state unchanged.
 half_batch    the second half of each batch gets the first half's answers.
@@ -16,25 +20,44 @@ misroute      each request gets the answer of the next one in its batch.
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 
+import jax.numpy as jnp
 import numpy as np
 
-from repro.store import make_store
+from repro.exec.topology import has_disk_tail
+from repro.store import TailWriter, make_store
 
-from .serving import VectorEngine
+from .serving import VectorEngine, ensure_room
 
 FAULTS = ("stale", "half_batch", "alter_answer", "misroute")
 VARIANTS = ("sound", "control") + FAULTS
 
 
+# rows rounded per block for a disk tail: rounding the whole corpus at once
+# would raise the device's peak by its bf16 and fp32 copies
+_ROUND_ROWS = 65536
+
+
+def _bf16(rows):
+    return rows.astype(jnp.bfloat16).astype(jnp.float32)
+
+
 def control_index(index, X, config: dict):
-    """(index, config) for the precision control of a built index: its
-    fp32 store, the configuration's precision, served as bf16."""
-    if config["store"] != "fp32":
-        raise ValueError(f"no precision control for a {config['store']!r} "
-                         f"store")
-    return (dataclasses.replace(index, store=make_store("bf16", X)),
-            {**config, "store": "bf16"})
+    """(index, config) for the precision control of a built index `X` was
+    built from: an fp32 store is served as bf16; an inexact store's fp32
+    rerank rows are rounded to bf16."""
+    if config["store"] == "fp32":
+        return (dataclasses.replace(index, store=make_store("bf16", X)),
+                {**config, "store": "bf16"})
+    if not has_disk_tail(index):
+        return dataclasses.replace(index, tail=_bf16(index.tail)), config
+    directory = Path(index.tail_path).parent
+    ensure_room(directory, X.size * 4)
+    writer = TailWriter(directory / "control-bf16.npy", X.shape[1])
+    for lo in range(0, X.shape[0], _ROUND_ROWS):
+        writer.append(np.asarray(_bf16(X[lo:lo + _ROUND_ROWS])))
+    return dataclasses.replace(index, tail_path=writer.finalize()), config
 
 
 class _Altered:

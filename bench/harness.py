@@ -12,14 +12,22 @@
              seed, and every answer is compared with the exact reference
              (`bench/reference.py`) under the cell's limits.
 
+A configuration whose fp32 rerank rows live on disk (`tail: "disk"`,
+`bench/serving.py`) has its tail written into a fresh directory in the
+system's temp directory, never under the checkout.  The `Stand` owns that
+directory and removes it once the answers are compared, or when a step
+raises; an `atexit` hook removes it at the latest.
+
 `bench/sweep.py` and `bench/control.py` drive the same pieces.
 """
 from __future__ import annotations
 
+import atexit
 import gc
 import json
 import shutil
 import sys
+import tempfile
 import threading
 import time
 from dataclasses import dataclass
@@ -97,7 +105,8 @@ class RunRecord:
 
 @dataclass
 class Stand:
-    """A cell's deployment, stood up from one seed."""
+    """A cell's deployment, stood up from one seed.  As a context manager
+    it removes its tail directory on leaving."""
 
     cell: Cell
     seed: int
@@ -106,6 +115,19 @@ class Stand:
     queries: np.ndarray              # the pool, on the host
     index: object
     build_s: float
+    tail_dir: str | None = None      # holds a disk tail's files
+
+    def close(self) -> None:
+        if self.tail_dir is not None:
+            shutil.rmtree(self.tail_dir, ignore_errors=True)
+            log(f"tail directory {self.tail_dir} removed")
+            self.tail_dir = None
+
+    def __enter__(self) -> "Stand":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
 
 
 @dataclass
@@ -121,10 +143,24 @@ def family_seed(seed: int) -> int:
     return (seed ^ (seed >> 31)) & 0x7FFFFFFF
 
 
+def tail_directory() -> str:
+    """A fresh directory for a disk tail in the system's temp directory,
+    removed at exit at the latest."""
+    path = tempfile.mkdtemp(prefix="bench-tail-")
+    atexit.register(shutil.rmtree, path, ignore_errors=True)
+    if Path(path).resolve().is_relative_to(ROOT):
+        shutil.rmtree(path)
+        raise SystemExit(f"[bench] error: the temp directory {path} lies "
+                         f"under the checkout {ROOT}; a tail is never "
+                         f"written there")
+    return path
+
+
 def stand_up(cell: Cell, seed: int,
              n: int | None = None) -> tuple[Stand, object]:
     """Data and index for `cell` from `seed`.  Returns the stand and the
-    device corpus (the caller drops it once it has built what it needs)."""
+    device corpus (the caller drops it once it has built what it needs).
+    The caller closes the stand (`with stand:`) once it is done."""
     import jax
 
     from . import serving
@@ -133,15 +169,24 @@ def stand_up(cell: Cell, seed: int,
     cfg = dict(cell.config)
     n = int(n or cfg["n"])
     pool = int(cell.traffic["pool"])
-    X, Q = make_data(cfg, n, pool, seed)
-    log(f"data made: {n} x {cfg['d']} corpus, {pool} pool queries")
-    t_b = time.perf_counter()
-    index = serving.build_index(X, cfg, float(cfg["w"]), family_seed(seed))
-    jax.block_until_ready(index)
-    build_s = time.perf_counter() - t_b
+    tail_dir = tail_directory() if serving.tail_of(cfg) == "disk" else None
+    try:
+        X, Q = make_data(cfg, n, pool, seed)
+        log(f"data made: {n} x {cfg['d']} corpus, {pool} pool queries")
+        t_b = time.perf_counter()
+        index = serving.build_index(X, cfg, float(cfg["w"]),
+                                    family_seed(seed), tail_dir)
+        jax.block_until_ready(index)
+        build_s = time.perf_counter() - t_b
+    except BaseException:
+        if tail_dir is not None:
+            shutil.rmtree(tail_dir, ignore_errors=True)
+        raise
     log(f"{cell.name} seed={seed} n={n} d={cfg['d']} w={cfg['w']!r}: "
-        f"index built in {build_s:.3f} s")
-    return Stand(cell, seed, n, cfg, np.asarray(Q), index, build_s), X
+        f"index built in {build_s:.3f} s"
+        + (f", fp32 tail in {tail_dir}" if tail_dir else ""))
+    return Stand(cell, seed, n, cfg, np.asarray(Q), index, build_s,
+                 tail_dir), X
 
 
 def serve(stand: Stand, X, variant: str = "sound"):
@@ -264,20 +309,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     tr = cell.traffic
     stand, X = stand_up(cell, seed, n)
-    router = serve(stand, X, variant)
-    del X  # an fp32 store keeps the rows it serves
-    log("router warm")
-    try:
-        compile_setup_s = watch.seconds.get("setup", 0.0)
-        watch.phase = "window"
-        win = drive(router, stand, seconds, seed, trace=trace)
-        watch.phase = "check"
-        log("every answer in")
-    finally:
-        router.shutdown(drain=False)
-    stand.index = None
-    del router
-    gc.collect()
+    with stand:  # removes a disk tail once the answers are compared
+        router = serve(stand, X, variant)
+        del X  # an fp32 store keeps the rows it serves
+        log("router warm")
+        try:
+            compile_setup_s = watch.seconds.get("setup", 0.0)
+            watch.phase = "window"
+            win = drive(router, stand, seconds, seed, trace=trace)
+            watch.phase = "check"
+            log("every answer in")
+        finally:
+            router.shutdown(drain=False)
+        stand.index = None
+        del router
+        gc.collect()
+        nums = check(stand, {variant: win.answers})[variant]
+        log("reference compared")
 
     answers = win.answers
     ok = [a for a in answers if a.error is None]
@@ -310,9 +358,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                 f"longest pause between answer bursts "
                 f"{float(np.max(pauses))!r} s: "
                 + ("complete" if reduced["complete"] else "events lost"))
-
-    nums = check(stand, {variant: answers})[variant]
-    log("reference compared")
     checks = {name: {"value": nums[name], "limit": lim}
               for name, lim in cell.limits["limits"].items()}
     correct = bool(ok) and all(c["value"] <= c["limit"]

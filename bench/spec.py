@@ -9,6 +9,18 @@ readers are files of their own, found by name:
                                    readings they were set from
     bench/metrics/<metric>.py      one reader per metric: `read(run)`
 
+A configuration's `tail` key says where an inexact store's fp32 rerank
+rows live: `"device"`, the default and what a configuration without the
+key gets, keeps them as a device array; `"disk"` writes them to an `.npy`
+file that every batch gathers its survivors' rows from on the host, as
+the program's split plan serves it.  `"disk"` with an fp32 store, or any
+other value, is an error.  The benchmark writes the file into a fresh
+directory in the system's temp directory (never under the checkout),
+with 1.1 x its size free or it exits non-zero, and removes the directory
+once the answers are compared or a step fails.  The file is read through
+the host's page cache as it was written, as a host of that size serves
+it: nothing drops the cache.
+
 Adding a configuration, a mix, a cell or a metric adds files and entries;
 no file here changes.
 """

@@ -53,40 +53,41 @@ def main() -> int:
         log(f"error: {cell.name} is not an open-loop cell")
         return 2
     stand, X = stand_up(cell, args.seed, args.n)
-    router = serve(stand, X)
-    del X
-    b = int(cell.traffic["max_batch"])
-    try:
-        for rate in (float(r) for r in args.rates.split(",")):
-            offsets = arrival_offsets({**cell.traffic, "rate_qps": rate},
-                                      args.seconds, args.seed)
-            win = drive(router, stand, args.seconds, args.seed,
-                        offsets=offsets)
-            ans = win.answers
-            mid = win.t0 + args.seconds / 2
+    with stand:  # removes a disk tail when the sweep ends
+        router = serve(stand, X)
+        del X
+        b = int(cell.traffic["max_batch"])
+        try:
+            for rate in (float(r) for r in args.rates.split(",")):
+                offsets = arrival_offsets({**cell.traffic, "rate_qps": rate},
+                                          args.seconds, args.seed)
+                win = drive(router, stand, args.seconds, args.seed,
+                            offsets=offsets)
+                ans = win.answers
+                mid = win.t0 + args.seconds / 2
 
-            def backlog(t):
-                return sum(1 for a in ans if a.t_submit <= t < a.t_done)
+                def backlog(t):
+                    return sum(1 for a in ans if a.t_submit <= t < a.t_done)
 
-            due = sum(1 for a in ans if mid <= a.t_due < win.t_end)
-            done = sum(1 for a in ans
-                       if a.error is None and mid <= a.t_done < win.t_end)
-            pct = percentiles_ms(a.t_done - a.t_due for a in ans
-                                 if a.error is None)
-            row = {"rate_qps": rate, "offered": len(ans),
-                   "answered_share": done / max(due, 1),
-                   "backlog_mid": backlog(mid),
-                   "backlog_close": backlog(win.t_end),
-                   "failed": sum(1 for a in ans if a.error is not None),
-                   "p50_ms": pct["p50_ms"], "p95_ms": pct["p95_ms"]}
-            row["keeps_up"] = (row["answered_share"] >= 0.98
-                               and row["failed"] == 0
-                               and row["backlog_close"]
-                               <= row["backlog_mid"] + b)
-            log(" ".join(f"{k}={v}" for k, v in row.items()))
-            print(json.dumps(row), flush=True)
-    finally:
-        router.shutdown(drain=False)
+                due = sum(1 for a in ans if mid <= a.t_due < win.t_end)
+                done = sum(1 for a in ans
+                           if a.error is None and mid <= a.t_done < win.t_end)
+                pct = percentiles_ms(a.t_done - a.t_due for a in ans
+                                     if a.error is None)
+                row = {"rate_qps": rate, "offered": len(ans),
+                       "answered_share": done / max(due, 1),
+                       "backlog_mid": backlog(mid),
+                       "backlog_close": backlog(win.t_end),
+                       "failed": sum(1 for a in ans if a.error is not None),
+                       "p50_ms": pct["p50_ms"], "p95_ms": pct["p95_ms"]}
+                row["keeps_up"] = (row["answered_share"] >= 0.98
+                                   and row["failed"] == 0
+                                   and row["backlog_close"]
+                                   <= row["backlog_mid"] + b)
+                log(" ".join(f"{k}={v}" for k, v in row.items()))
+                print(json.dumps(row), flush=True)
+        finally:
+            router.shutdown(drain=False)
     return 0
 
 
